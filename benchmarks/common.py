@@ -11,13 +11,11 @@ RESULTS: Dict[str, Dict] = {}
 
 
 def _block(out):
-    """Wait for async jax work; harmless on non-jax results."""
-    try:
-        import jax
+    """Wait for async jax work (non-jax results pass through); a device
+    error raises here rather than ending up inside a timing."""
+    import jax
 
-        return jax.block_until_ready(out)
-    except Exception:
-        return out
+    return jax.block_until_ready(out)
 
 
 def time_us(fn: Callable, *args, warmup: int = 2, iters: int = 10) -> float:

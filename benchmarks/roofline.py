@@ -8,7 +8,6 @@ import json
 import os
 
 from benchmarks.common import emit
-from repro.launch.hlo_stats import HBM_BW, ICI_BW, PEAK_FLOPS
 
 DRYRUN_DIR = os.path.join(
     os.path.dirname(os.path.dirname(os.path.abspath(__file__))),

@@ -27,6 +27,7 @@ from repro.hostdevices import force_host_devices
 force_host_devices()
 
 from benchmarks import common
+from repro.compile_cache import enable_compile_cache
 from benchmarks import (
     committee_ablation,
     consensus_cost,
@@ -62,6 +63,7 @@ def main() -> None:
                     help="paper-scale sweeps (slow)")
     ap.add_argument("--only", default=None, choices=list(ALL))
     args = ap.parse_args()
+    enable_compile_cache()
 
     names = [args.only] if args.only else list(ALL)
     failures = 0

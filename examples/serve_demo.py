@@ -1,17 +1,20 @@
-"""Batched serving demo: prefill + decode with the production decode step
-(smoke-sized gemma3: 5:1 local:global attention with ring-buffer caches).
+"""Continuous-batching serving demo: the serving CLI on a smoke-sized
+gemma3 (5:1 local:global attention with ring-buffer caches), a Poisson
+trace over two prompt lengths.
 
   PYTHONPATH=src python examples/serve_demo.py
 """
-import subprocess
-import sys
 import os
+import sys
 
 if __name__ == "__main__":
     repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-    env = dict(os.environ, PYTHONPATH=os.path.join(repo, "src"))
-    sys.exit(subprocess.call(
-        [sys.executable, "-m", "repro.launch.serve", "--arch", "gemma3-4b",
-         "--smoke", "--batch", "4", "--prompt-len", "64", "--gen", "16"],
-        env=env,
-    ))
+    sys.path.insert(0, os.path.join(repo, "src"))
+    from repro.launch import serve
+
+    sys.argv = [
+        "serve", "--arch", "gemma3-4b", "--smoke", "--slots", "4",
+        "--requests", "8", "--prompt-lens", "32", "64",
+        "--gen-lens", "8", "16", "--max-len", "96",
+    ]
+    serve.main()

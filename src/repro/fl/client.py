@@ -148,18 +148,18 @@ def _int8_score_program(adapter: ModelAdapter, unravel, interpret: bool):
     from jax.flatten_util import ravel_pytree
 
     from repro.kernels.fused_score import make_fused_candidates_fn
-    from repro.kernels.ops import _pad_to_block
+    from repro.kernels.ops import _pad_to_block, scales_from_tiles
     from repro.kernels.quantize import quantize_stack_kernel
 
     fused_candidates = make_fused_candidates_fn(interpret=interpret)
 
     def score(params, stack, vx, vy):
         D = stack.shape[1]
-        q, s = quantize_stack_kernel(_pad_to_block(stack)[0],
-                                     interpret=interpret)
+        q, tiles = quantize_stack_kernel(_pad_to_block(stack)[0],
+                                         interpret=interpret)
         flat, _ = ravel_pytree(params)
         base = _pad_to_block(flat.astype(jnp.float32))[0]
-        cands = fused_candidates(base, q, s)
+        cands = fused_candidates(base, q, tiles)
 
         def one_candidate(row, vx, vy):
             candidate = unravel(row[:D])
@@ -170,7 +170,7 @@ def _int8_score_program(adapter: ModelAdapter, unravel, interpret: bool):
         scores = jax.vmap(one_candidate, in_axes=(0, None, None))(
             cands, vx, vy
         )
-        return scores, q, s
+        return scores, q, scales_from_tiles(tiles)
 
     return score
 

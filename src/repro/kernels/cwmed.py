@@ -60,7 +60,7 @@ def _cwmed_kernel(x_ref, o_ref, *, K: int):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def cwmed_kernel(stack: jnp.ndarray, *, interpret: bool = True) -> jnp.ndarray:
+def cwmed_kernel(stack: jnp.ndarray, *, interpret: bool) -> jnp.ndarray:
     """stack: (K, D) f32 -> (D,) f32 per-coordinate median."""
     K, D = stack.shape
     assert D % BLOCK_D == 0, D
@@ -82,7 +82,7 @@ def _trimmed_mean_kernel(x_ref, o_ref, *, K: int, trim: int):
 
 @functools.partial(jax.jit, static_argnames=("trim", "interpret"))
 def trimmed_mean_kernel(stack: jnp.ndarray, *, trim: int,
-                        interpret: bool = True) -> jnp.ndarray:
+                        interpret: bool) -> jnp.ndarray:
     """stack: (K, D) f32 -> (D,) f32 coordinate-wise trimmed mean."""
     K, D = stack.shape
     assert D % BLOCK_D == 0, D

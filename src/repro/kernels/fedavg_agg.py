@@ -29,7 +29,7 @@ def _fedavg_kernel(w_ref, x_ref, o_ref):
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def fedavg_agg_kernel(stack: jnp.ndarray, weights: jnp.ndarray,
-                      *, interpret: bool = True) -> jnp.ndarray:
+                      *, interpret: bool) -> jnp.ndarray:
     """stack: (K, D) f32, weights: (K,) normalized.  Returns (D,) f32.
 
     D must be a multiple of BLOCK_D (ops.py pads)."""

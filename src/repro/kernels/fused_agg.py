@@ -19,7 +19,8 @@ HBM traffic per grid step (tile of BLOCK_D lanes, K committee updates):
 
 i.e. one int8 read of the stack + one write of the result — ~12x fewer
 bytes on the dominant read than the f32 staged path the runtime used to
-run.  Scales ride along in the same pass: (K, 1) f32 per tile.
+run.  Scales ride along in the same pass: one (K, 1) f32 block per tile
+(``quantize.scale_spec``).
 """
 from __future__ import annotations
 
@@ -34,6 +35,7 @@ from repro.kernels.cwmed import (
     sort_rows,
     trimmed_mean_of_sorted,
 )
+from repro.kernels.quantize import quantize_rows, scale_spec
 from repro.kernels.tiling import BLOCK_D
 
 METHODS = ("fedavg", "cwmed", "trimmed_mean")
@@ -63,14 +65,11 @@ def _fused_kernel_qout(w_ref, s_ref, x_ref, q_ref, so_ref, *,
     agg = _reduce_tile(
         w_ref[...], s_ref[...], x_ref[...], method=method, trim=trim
     )
-    amax = jnp.max(jnp.abs(agg))
-    scale = jnp.where(amax > 0, amax / 127.0, 1.0)
-    q_ref[0, :] = jnp.clip(jnp.round(agg / scale), -127, 127).astype(jnp.int8)
-    so_ref[0, 0] = scale
+    q_ref[...], so_ref[...] = quantize_rows(agg[None, :])
 
 
 def make_fused_agg_fn(*, method: str = "fedavg", trim: int = 1,
-                      quantize_out: bool = False, interpret: bool = True):
+                      quantize_out: bool = False, interpret: bool):
     """Unjitted ``(qstack, scales, weights) -> out`` closure over the static
     kernel knobs — the form the sharded aggregation path composes under
     ``shard_map`` (each device invokes it on its D-shard of the stack;
@@ -89,13 +88,13 @@ def _fused_agg(
     method: str = "fedavg",
     trim: int = 1,
     quantize_out: bool = False,
-    interpret: bool = True,
+    interpret: bool,
 ):
     K, D = qstack.shape
     assert D % BLOCK_D == 0, D
     assert qstack.dtype == jnp.int8, qstack.dtype
     nblk = D // BLOCK_D
-    assert scales.shape == (K, nblk), (scales.shape, K, nblk)
+    assert scales.shape == (nblk, K, 1), (scales.shape, K, nblk)
     if method not in METHODS:
         raise ValueError(f"unknown method {method!r}")
     if method == "trimmed_mean" and not 0 <= 2 * trim < K:
@@ -103,7 +102,7 @@ def _fused_agg(
 
     in_specs = [
         pl.BlockSpec((K, 1), lambda i: (0, 0)),          # weights
-        pl.BlockSpec((K, 1), lambda i: (0, i)),          # this tile's scales
+        scale_spec(K),                                   # this tile's scales
         pl.BlockSpec((K, BLOCK_D), lambda i: (0, i)),    # int8 tile
     ]
     operands = (weights.reshape(K, 1).astype(jnp.float32), scales, qstack)
@@ -121,17 +120,15 @@ def _fused_agg(
         functools.partial(_fused_kernel_qout, method=method, trim=trim),
         grid=(nblk,),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec((1, BLOCK_D), lambda i: (0, i)),
-            pl.BlockSpec((1, 1), lambda i: (0, i)),
-        ],
+        out_specs=[pl.BlockSpec((1, BLOCK_D), lambda i: (0, i)),
+                   scale_spec(1)],
         out_shape=[
             jax.ShapeDtypeStruct((1, D), jnp.int8),
-            jax.ShapeDtypeStruct((1, nblk), jnp.float32),
+            jax.ShapeDtypeStruct((nblk, 1, 1), jnp.float32),
         ],
         interpret=interpret,
     )(*operands)
-    return q[0], s[0]
+    return q[0], s
 
 
 @functools.partial(
@@ -145,12 +142,13 @@ def fused_agg_kernel(
     method: str = "fedavg",
     trim: int = 1,
     quantize_out: bool = False,
-    interpret: bool = True,
+    interpret: bool,
 ):
-    """qstack: (K, D) int8; scales: (K, D // BLOCK_D) f32; weights: (K,)
-    normalized (ignored unless method == "fedavg").
+    """qstack: (K, D) int8; scales: (D // BLOCK_D, K, 1) f32; weights:
+    (K,) normalized (ignored unless method == "fedavg").
 
-    Returns (D,) f32, or (q (D,) int8, out_scales (D // BLOCK_D,) f32) when
-    ``quantize_out`` — everything in a single grid pass over the stack."""
+    Returns (D,) f32, or (q (D,) int8, out_scales (D // BLOCK_D, 1, 1) f32)
+    when ``quantize_out`` — everything in a single grid pass over the
+    stack."""
     return _fused_agg(qstack, scales, weights, method=method, trim=trim,
                       quantize_out=quantize_out, interpret=interpret)

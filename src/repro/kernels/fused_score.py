@@ -26,6 +26,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.quantize import scale_spec
 from repro.kernels.tiling import BLOCK_D
 
 
@@ -35,7 +36,7 @@ def _fused_candidates(p_ref, s_ref, q_ref, o_ref):
     o_ref[...] = p_ref[0, :] + q_ref[...].astype(jnp.float32) * s_ref[...]
 
 
-def make_fused_candidates_fn(*, interpret: bool = True):
+def make_fused_candidates_fn(*, interpret: bool):
     """Unjitted ``(base, qstack, scales) -> candidates`` closure over the
     static kernel knobs — the form the validation score programs compose
     (``repro.fl.client._int8_score_program``: single-device jitted and
@@ -45,19 +46,19 @@ def make_fused_candidates_fn(*, interpret: bool = True):
 
 
 def _fused(base: jnp.ndarray, qstack: jnp.ndarray, scales: jnp.ndarray,
-           *, interpret: bool = True):
+           *, interpret: bool):
     K, D = qstack.shape
     assert D % BLOCK_D == 0, D
     assert qstack.dtype == jnp.int8, qstack.dtype
     assert base.shape == (D,), (base.shape, D)
     nblk = D // BLOCK_D
-    assert scales.shape == (K, nblk), (scales.shape, K, nblk)
+    assert scales.shape == (nblk, K, 1), (scales.shape, K, nblk)
     return pl.pallas_call(
         _fused_candidates,
         grid=(nblk,),
         in_specs=[
             pl.BlockSpec((1, BLOCK_D), lambda i: (0, i)),   # base params tile
-            pl.BlockSpec((K, 1), lambda i: (0, i)),         # this tile's scales
+            scale_spec(K),                                  # this tile's scales
             pl.BlockSpec((K, BLOCK_D), lambda i: (0, i)),   # int8 tiles
         ],
         out_specs=pl.BlockSpec((K, BLOCK_D), lambda i: (0, i)),
@@ -68,8 +69,8 @@ def _fused(base: jnp.ndarray, qstack: jnp.ndarray, scales: jnp.ndarray,
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def fused_candidates_kernel(base: jnp.ndarray, qstack: jnp.ndarray,
-                            scales: jnp.ndarray, *, interpret: bool = True):
+                            scales: jnp.ndarray, *, interpret: bool):
     """base: (D,) f32 global params; qstack: (K, D) int8 update rows;
-    scales: (K, D // BLOCK_D) f32.  Returns (K, D) f32 candidate rows
+    scales: (D // BLOCK_D, K, 1) f32.  Returns (K, D) f32 candidate rows
     ``base + dequant(qstack_k)`` in a single grid pass over the stack."""
     return _fused(base, qstack, scales, interpret=interpret)

@@ -28,15 +28,21 @@ from repro.kernels.cwmed import cwmed_kernel, trimmed_mean_kernel
 from repro.kernels.fedavg_agg import fedavg_agg_kernel
 from repro.kernels.tiling import BLOCK_D
 from repro.kernels.fused_agg import METHODS, fused_agg_kernel
-from repro.kernels.quantize import (
-    dequantize_kernel,
-    quantize_kernel,
-    quantize_stack_kernel,
-)
+from repro.kernels.quantize import dequantize_kernel, quantize_stack_kernel
 
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def scales_to_tiles(scales: jnp.ndarray) -> jnp.ndarray:
+    """Chain scale layout (K, nblk) -> the kernels' (nblk, K, 1) layout."""
+    return scales.T[:, :, None]
+
+
+def scales_from_tiles(tiles: jnp.ndarray) -> jnp.ndarray:
+    """The kernels' (nblk, K, 1) scale layout -> chain layout (K, nblk)."""
+    return tiles[:, :, 0].T
 
 
 def padded_dim(d: int) -> int:
@@ -115,19 +121,17 @@ def trimmed_mean(stack: jnp.ndarray, trim: int = 1) -> jnp.ndarray:
 # quantized representation: codec + fused one-pass aggregation
 # ----------------------------------------------------------------------
 def quantize(x: jnp.ndarray):
-    """(D,) -> (q int8 (D,), scales, D) — chain-storage codec."""
-    D = x.shape[0]
-    if D == 0:  # zero-size pytrees: nothing to tile, nothing to store
-        return jnp.zeros((0,), jnp.int8), jnp.zeros((0,), jnp.float32), 0
-    padded, _ = _pad_to_block(x)
-    q, s = quantize_kernel(padded, interpret=_interpret())
-    return q, s, D
+    """(D,) -> (q int8 (D,), scales, D) — chain-storage codec: the K = 1
+    case of ``quantize_stack``."""
+    q, s, D = quantize_stack(x[None])
+    return q[0], s[0], D
 
 
 def dequantize(q: jnp.ndarray, scales: jnp.ndarray, D: int) -> jnp.ndarray:
     if D == 0:
         return jnp.zeros((0,), jnp.float32)
-    out = dequantize_kernel(q, scales, interpret=_interpret())
+    out = dequantize_kernel(q, scales.reshape(-1, 1, 1),
+                            interpret=_interpret())
     return out[:D]
 
 
@@ -141,8 +145,8 @@ def quantize_stack(stack: jnp.ndarray):
     if D == 0:
         return jnp.zeros((K, 0), jnp.int8), jnp.zeros((K, 0), jnp.float32), 0
     padded, _ = _pad_to_block(stack)
-    q, s = quantize_stack_kernel(padded, interpret=_interpret())
-    return q, s, D
+    q, tiles = quantize_stack_kernel(padded, interpret=_interpret())
+    return q, scales_from_tiles(tiles), D
 
 
 def aggregate_quantized(
@@ -164,12 +168,12 @@ def aggregate_quantized(
     true_d = Dpad if D is None else D
     w = _normalize_weights(K, weights)
     out = fused_agg_kernel(
-        q, scales, w, method=method, trim=trim,
+        q, scales_to_tiles(scales), w, method=method, trim=trim,
         quantize_out=quantize_out, interpret=_interpret(),
     )
     if quantize_out:
         q_out, s_out = out
-        return q_out, s_out, true_d
+        return q_out, s_out.reshape(-1), true_d
     return out[:true_d]
 
 
@@ -192,7 +196,8 @@ def candidates_from_quantized(
     K, Dpad = q.shape
     true_d = Dpad if D is None else D
     padded, _ = _pad_to_block(base.astype(jnp.float32))
-    out = fused_candidates_kernel(padded, q, scales, interpret=_interpret())
+    out = fused_candidates_kernel(padded, q, scales_to_tiles(scales),
+                                  interpret=_interpret())
     return out[:, :true_d]
 
 
@@ -226,7 +231,8 @@ def make_quantize_stack_sharded(mesh, axis: str = "data"):
     interpret = _interpret()
 
     def _shard(chunk):                         # (K, Dpad / ndev) per device
-        return quantize_stack_kernel(chunk, interpret=interpret)
+        q, tiles = quantize_stack_kernel(chunk, interpret=interpret)
+        return q, scales_from_tiles(tiles)
 
     sharded = shard_map(_shard, mesh=mesh, in_specs=P(None, axis),
                         out_specs=(P(None, axis), P(None, axis)))
@@ -259,7 +265,11 @@ def make_aggregate_quantized_sharded(mesh, axis: str = "data",
 
     fused = make_fused_agg_fn(method=method, trim=trim,
                               interpret=_interpret())
-    sharded = shard_map(fused, mesh=mesh,
+
+    def _shard(q, scales, weights):            # (K, Dpad / ndev) per device
+        return fused(q, scales_to_tiles(scales), weights)
+
+    sharded = shard_map(_shard, mesh=mesh,
                         in_specs=(P(None, axis), P(None, axis), P()),
                         out_specs=P(axis))
 

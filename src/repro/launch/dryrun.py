@@ -328,6 +328,7 @@ def dryrun_one(
         terms = hlo_stats.roofline_terms(
             flops=flops, bytes_accessed=bytes_acc,
             collective_bytes=float(coll.total_bytes), chips=1,
+            device_kind="TPU v5 lite",  # the production mesh's chip
         )  # all values are per-device post-SPMD; chips=1 keeps units right
         rec.update({
             "chips": chips,

@@ -9,7 +9,8 @@ Collectives inside ``while`` loops (the layer-unit scan!) execute
 trip-count-many times but appear once in the text, so the parser walks the
 computation call graph and multiplies by XLA's ``known_trip_count``.
 
-Hardware model: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link ICI.
+Hardware model: the published peaks of the chip, looked up by
+``jax.Device.device_kind`` in ``PEAKS``.
 """
 from __future__ import annotations
 
@@ -17,9 +18,28 @@ import re
 from dataclasses import dataclass, field
 from typing import Dict, List, Tuple
 
-PEAK_FLOPS = 197e12         # bf16 per chip
-HBM_BW = 819e9              # bytes/s per chip
-ICI_BW = 50e9               # bytes/s per link
+# Published per-chip peaks keyed by ``jax.Device.device_kind``.  A device
+# that is not listed is an error, not a default: add it with its source.
+PEAKS: Dict[str, Dict[str, object]] = {
+    "TPU v5 lite": {
+        "source": 'Google Cloud documentation, "TPU v5e"',
+        "flops_bf16": 197e12,       # FLOP/s
+        "ops_int8": 393e12,         # OP/s
+        "hbm_bytes_s": 819e9,       # bytes/s, 16 GB HBM
+        # 1,600 Gbit/s of chip-to-chip interconnect over 4 links
+        "ici_link_bytes_s": 50e9,
+    },
+}
+
+
+def peaks(device_kind: str) -> Dict[str, object]:
+    """The ``PEAKS`` entry for a device kind; raises for one not listed."""
+    if device_kind not in PEAKS:
+        raise KeyError(
+            f"no published peaks for device_kind {device_kind!r}; "
+            f"known: {sorted(PEAKS)}"
+        )
+    return PEAKS[device_kind]
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -301,15 +321,17 @@ def roofline_terms(
     bytes_accessed: float,
     collective_bytes: float,
     chips: int,
+    device_kind: str,
 ) -> Dict[str, float]:
     """The three §Roofline terms, in seconds (per step, whole mesh).
 
     flops / bytes_accessed are cost_analysis() *per-device* numbers times
     `chips` when aggregated by the caller; here we take WHOLE-PROGRAM totals
     and divide by the mesh."""
-    compute_s = flops / (chips * PEAK_FLOPS)
-    memory_s = bytes_accessed / (chips * HBM_BW)
-    collective_s = collective_bytes / (chips * ICI_BW)
+    pk = peaks(device_kind)
+    compute_s = flops / (chips * pk["flops_bf16"])
+    memory_s = bytes_accessed / (chips * pk["hbm_bytes_s"])
+    collective_s = collective_bytes / (chips * pk["ici_link_bytes_s"])
     dominant = max(
         ("compute", compute_s), ("memory", memory_s),
         ("collective", collective_s), key=lambda kv: kv[1],

@@ -12,20 +12,15 @@ import jax
 import numpy as np
 
 
-def _axis_type_kwargs(n: int) -> dict:
-    """``axis_types=(Auto,)*n`` where the running jax has AxisType; {} on
-    older versions (pre-0.5 ``make_mesh`` has no such kwarg)."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return {}
-    return {"axis_types": (axis_type.Auto,) * n}
+def _auto_axes(n: int) -> tuple:
+    return (jax.sharding.AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 = 256 chips per pod; 2 pods = 512 chips when multi_pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=_auto_axes(len(axes)))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):
@@ -34,7 +29,7 @@ def make_host_mesh(data: int = 1, model: int = 1):
     if data * model > n:
         raise ValueError(f"need {data*model} devices, have {n}")
     return jax.make_mesh((data, model), ("data", "model"),
-                         **_axis_type_kwargs(2))
+                         axis_types=_auto_axes(2))
 
 
 ROUND_AXIS = "data"   # the axis the round engine shards clients / D over

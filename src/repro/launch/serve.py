@@ -37,6 +37,9 @@ def main():
                     help="static-batch baseline admission policy")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
 
     from repro.configs import registry
     from repro.serve import ServeEngine, make_poisson_trace

@@ -153,6 +153,9 @@ def main():
     ap.add_argument("--malicious", type=float, default=0.0)
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    from repro.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.driver == "lm":
         run_lm(args)
     else:

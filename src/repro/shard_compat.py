@@ -1,10 +1,6 @@
-"""Version-tolerant ``shard_map``: one import site for every sharded path.
-
-jax moved ``shard_map`` from ``jax.experimental.shard_map`` to the top-level
-namespace and renamed the replication-check kwarg (``check_rep`` ->
-``check_vma``) across 0.4.x -> 0.6.x.  The round engine, the MoE
-expert-parallel path and the sharded kernels all go through this shim so the
-repo runs on whichever jax the container bakes in.
+"""``shard_map`` with the repo's default: one import site for every
+sharded path (the round engine, the MoE expert-parallel path and the
+sharded kernels).
 
 ``check`` defaults to False: the sharded kernels invoke ``pallas_call``
 inside the mapped body, and pallas has no replication rule — the check
@@ -14,18 +10,8 @@ from __future__ import annotations
 
 import jax
 
-if hasattr(jax, "shard_map"):                     # jax >= 0.6
-    _shard_map = jax.shard_map
-    _CHECK_KW = "check_vma"
-else:                                             # jax 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _CHECK_KW = "check_rep"
-
 
 def shard_map(f, mesh, in_specs, out_specs, check: bool = False):
-    """``jax.shard_map`` with the replication-check kwarg normalized."""
-    return _shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-        **{_CHECK_KW: check},
-    )
+    """``jax.shard_map`` with the replication check off by default."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check)

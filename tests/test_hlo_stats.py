@@ -83,7 +83,8 @@ def test_decode_per_token_stats_rejects_bad_batch():
 
 def test_roofline_terms_dominance():
     t = H.roofline_terms(
-        flops=197e12, bytes_accessed=819e9, collective_bytes=100e9, chips=1
+        flops=197e12, bytes_accessed=819e9, collective_bytes=100e9, chips=1,
+        device_kind="TPU v5 lite",
     )
     assert t["compute_s"] == pytest.approx(1.0)
     assert t["memory_s"] == pytest.approx(1.0)
@@ -95,3 +96,12 @@ def test_shape_bytes_dtype_table():
     assert H._shape_bytes("bf16[2,3]") == 12
     assert H._shape_bytes("f32[10] s8[4]") == 44
     assert H._shape_bytes("pred[8]") == 8
+
+
+def test_peaks_unknown_device_kind_raises():
+    assert H.peaks("TPU v5 lite")["source"]
+    with pytest.raises(KeyError, match="no published peaks"):
+        H.peaks("cpu")
+    with pytest.raises(KeyError):
+        H.roofline_terms(flops=1.0, bytes_accessed=1.0, collective_bytes=0.0,
+                         chips=1, device_kind="TPU v9000")
