@@ -18,11 +18,10 @@ sub-aggregation), shard_mapped training over the forced host devices.
 
 Wall-clock per round is reported too (first round, so XLA compilation is
 included — these rows track memory scaling, not steady-state latency; the
-steady-state stage timings live in ``round_bench``).
+round's per-stage host time is ``BFLCRuntime.stage_timings``).
 
-``benchmarks.run`` merges these rows into ``BENCH_round.json`` alongside
-the flat round-loop stage timings.  Standalone CLI (the CI bench smoke
-step runs ``--smoke``):
+``benchmarks.run`` merges these rows into ``BENCH_round.json``.
+Standalone CLI (the CI bench smoke step runs ``--smoke``):
 
   PYTHONPATH=src python -m benchmarks.hier_bench --smoke
   PYTHONPATH=src python -m benchmarks.hier_bench --full   # adds P=102400

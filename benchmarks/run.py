@@ -1,8 +1,7 @@
 """Benchmark harness entry: one function per paper table/figure + systems
 benchmarks.  Prints ``name,us_per_call,derived`` CSV lines and writes the
-kernel rows to ``BENCH_kernels.json`` and the round-loop stage timings to
-``BENCH_round.json`` (name -> {us, bytes}) so the perf trajectory is
-machine-trackable across PRs.
+kernel rows to ``BENCH_kernels.json`` and the hierarchical-round memory
+rows to ``BENCH_round.json`` (name -> {us, bytes}).
 
   PYTHONPATH=src python -m benchmarks.run [--full] [--only NAME]
 """
@@ -17,7 +16,7 @@ import traceback
 
 from repro.hostdevices import force_host_devices
 
-# round_bench's sharded-engine rows need multiple devices; the flag must
+# the sharded-engine sections need multiple devices; the flag must
 # land before jax initializes its backend (first device query), i.e. before
 # any benchmark runs.  An externally-set force_host flag wins.  NOTE: this
 # applies to EVERY section (single-device work still runs on device 0, but
@@ -36,7 +35,6 @@ from benchmarks import (
     hier_bench,
     kernel_bench,
     roofline,
-    round_bench,
     serve_bench,
     storage_opt,
     table1_accuracy,
@@ -46,7 +44,6 @@ ALL = {
     "fig3_attack_probability": fig3_attack_probability.run,
     "consensus_cost": consensus_cost.run,
     "kernel_bench": kernel_bench.run,
-    "round_bench": round_bench.run,
     "hier_bench": hier_bench.run,
     "serve_bench": serve_bench.run,
     "storage_opt": storage_opt.run,
@@ -88,16 +85,13 @@ def main() -> None:
         out = root / "BENCH_kernels.json"
         out.write_text(json.dumps(sections["kernel_bench"], indent=2) + "\n")
         print(f"# wrote {out}")
-    # BENCH_round.json carries the flat round-loop stage timings AND the
-    # hierarchical-round memory rows: merge whichever sections ran into the
-    # existing snapshot so a --only run of one doesn't drop the other's
-    # rows (renamed rows must be pruned by hand — keys merge, not replace)
-    ran = [s for s in ("round_bench", "hier_bench") if s in sections]
-    if ran:
+    # BENCH_round.json carries the hierarchical-round memory rows: merged
+    # into the existing snapshot (renamed rows must be pruned by hand —
+    # keys merge, not replace)
+    if "hier_bench" in sections:
         out = root / "BENCH_round.json"
         data = json.loads(out.read_text()) if out.exists() else {}
-        for section in ran:
-            data.update(sections[section])
+        data.update(sections["hier_bench"])
         out.write_text(json.dumps(data, indent=2) + "\n")
         print(f"# wrote {out}")
     # serving rows live in their own snapshot: same merge discipline as

@@ -56,11 +56,12 @@ Design
   work is awaited where a stage half genuinely consumes it
   (``train_finalize``'s host gather, ``validate_finalize``'s score
   gather, the tail's chain digests) plus one final sync in the reward
-  node.  Per-node host time is accumulated into ``ctx.timings`` under
-  the same ``STAGE_TIMING_KEYS`` buckets as the sequential engine
-  (dispatch time + whatever blocking its own sync point pays), so
-  BENCH_round rows keep their schema; buckets are host-attributed —
-  overlapped device time lands in whichever bucket blocked on it.
+  node (``reward.wait``).  Each node runs inside a ``bflc.<bucket>``
+  span whose host time is accumulated into ``ctx.timings`` under the
+  same ``STAGE_TIMING_KEYS`` buckets as the sequential engine (dispatch
+  time + whatever blocking its own sync point pays), with the same
+  dotted phase keys; buckets are host-attributed — overlapped device
+  time lands in whichever bucket blocked on it.
 * **Failure.**  A node that raises aborts the run immediately: no tail
   node has run, so nothing was appended to the chain — a mid-ring
   failure cannot tear the chain layout (gated in tests), and in-flight
@@ -74,7 +75,6 @@ suite: tests/test_async_round.py).
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional
 
@@ -85,6 +85,7 @@ from repro.fl.pipeline import (
     RoundPipeline,
     STAGE_TIMING_KEYS,
     _sync_tree,
+    stage_span,
 )
 
 # per-cohort RoundContext fields staged between ring slots and the shared
@@ -285,7 +286,8 @@ class _AsyncRoundRun:
             pipe.rewarder(ctx)
             # the round's final sync point: nothing a caller observes
             # (new params, chain, logs) may still be in flight
-            jax.block_until_ready(_sync_tree(ctx))
+            with stage_span(ctx, "reward.wait"):
+                jax.block_until_ready(_sync_tree(ctx))
 
         for key, fn in (("pack", pipe.packer),
                         ("aggregate", pipe.aggregator),
@@ -356,21 +358,18 @@ class _AsyncRoundRun:
 
     def _exec(self, node: StageNode) -> None:
         ctx = self.ctx
-        t0 = time.perf_counter()
-        slot = node.slot
-        if slot is not None:
-            for f in SLOT_FIELDS:
-                setattr(ctx, f, getattr(slot, f))
-        try:
-            node.fn(ctx)
-        finally:
+        with stage_span(ctx, node.bucket):
+            slot = node.slot
             if slot is not None:
                 for f in SLOT_FIELDS:
-                    setattr(slot, f, getattr(ctx, f))
+                    setattr(ctx, f, getattr(slot, f))
+            try:
+                node.fn(ctx)
+            finally:
+                if slot is not None:
+                    for f in SLOT_FIELDS:
+                        setattr(slot, f, getattr(ctx, f))
         node.done = True
-        ctx.timings[node.bucket] = (
-            ctx.timings.get(node.bucket, 0.0) + (time.perf_counter() - t0)
-        )
         if node.kind == "sample":
             self._after_sample(node)
         elif node.kind == "validate_finalize":

@@ -53,6 +53,7 @@ from repro.fl.pipeline import (
     default_stage_names,
     register,
     resolve,
+    stage_span,
 )
 
 
@@ -240,15 +241,16 @@ class HierValidator:
         # tier-2 validation data: one batch per round-committee member,
         # drawn up front (slice loops must not perturb the draw order
         # relative to how many slices ran)
-        vpairs = [
-            sample_client_batches(
-                rng, ctx.data.client_images[j], ctx.data.client_labels[j],
-                1, cfg.val_batch,
-            )
-            for j in ctx.round_committee
-        ]
-        st.val_x2 = np.stack([p[0][0] for p in vpairs])
-        st.val_y2 = np.stack([p[1][0] for p in vpairs])
+        with stage_span(ctx, "validate.batches"):
+            vpairs = [
+                sample_client_batches(
+                    rng, ctx.data.client_images[j], ctx.data.client_labels[j],
+                    1, cfg.val_batch,
+                )
+                for j in ctx.round_committee
+            ]
+            st.val_x2 = np.stack([p[0][0] for p in vpairs])
+            st.val_y2 = np.stack([p[1][0] for p in vpairs])
 
     # dispatch swaps in the slice sub-committee and runs the inner
     # validator's prepare (which draws the slice's val batches) — the
@@ -360,7 +362,9 @@ def _tier2_scores(ctx: RoundContext, st: HierState) -> np.ndarray:
         scores = ctx.score_matrix_fn(
             ctx.params, stacked, st.val_x2, st.val_y2
         )
-    return np.asarray(scores)[:n]
+    with stage_span(ctx, "pack.wait"):
+        scores = np.asarray(scores)
+    return scores[:n]
 
 
 @register("packer", "hier")
@@ -429,24 +433,26 @@ def pack_hier(ctx: RoundContext) -> None:
     ctx.weights = ctx.packed_scores if cfg.weight_by_score else None
 
     quantized = bool(getattr(cfg, "quantize_chain", False))
-    for r, s_idx in zip(recs, packed_slices):
-        if quantized:
-            ctx.chain.append_update(st.sub_blobs[s_idx], r.uploader,
-                                    r.median_score, encoded=True)
-        else:
-            ctx.chain.append_update(st.sub_aggregates[s_idx], r.uploader,
-                                    r.median_score)
-        ctx.manager.nodes[r.uploader].score_history.append(r.median_score)
-    ctx.chain.append_committee({
-        "members": np.asarray(ctx.round_committee, np.int64),
-        "uploaders": np.asarray(st.sub_uploaders, np.int64),
-        "scores": np.asarray(honest, np.float32),
-        "medians": np.asarray(medians, np.float32),
-        "accepted": np.asarray(
-            [any(r.uploader == st.sub_uploaders[i] and r.accepted
-                 for r in t2.records) for i in range(S)]
-        ),
-    })
+    with stage_span(ctx, "pack.chain"):
+        for r, s_idx in zip(recs, packed_slices):
+            if quantized:
+                ctx.chain.append_update(st.sub_blobs[s_idx], r.uploader,
+                                        r.median_score, encoded=True)
+            else:
+                ctx.chain.append_update(st.sub_aggregates[s_idx], r.uploader,
+                                        r.median_score)
+            ctx.manager.nodes[r.uploader].score_history.append(
+                r.median_score)
+        ctx.chain.append_committee({
+            "members": np.asarray(ctx.round_committee, np.int64),
+            "uploaders": np.asarray(st.sub_uploaders, np.int64),
+            "scores": np.asarray(honest, np.float32),
+            "medians": np.asarray(medians, np.float32),
+            "accepted": np.asarray(
+                [any(r.uploader == st.sub_uploaders[i] and r.accepted
+                     for r in t2.records) for i in range(S)]
+            ),
+        })
     if quantized:
         # stage the packed blobs for the fused aggregators — same
         # (q, scales, d, unravel) contract as the flat int8 packers

@@ -18,8 +18,10 @@ module exposes the round that way:
 * ``RoundPipeline`` drives the stages: sample/train/validate loop over
   cohorts until k qualified updates accumulate (the smart-contract
   trigger), then pack -> aggregate -> elect -> reward.  Every stage call
-  is timed into ``ctx.timings`` (exported by ``benchmarks/round_bench``
-  as ``BENCH_round.json``).
+  is a ``bflc.<stage>`` span (``repro.tracing``) whose host seconds land
+  in ``ctx.timings`` under its ``STAGE_TIMING_KEYS`` bucket; dotted keys
+  (``train.batches``, ``validate.wait``, ``pack.chain``, ...) time the
+  phases inside a bucket.
 
 ``BFLCRuntime`` is a thin facade over the default BFLC stage set;
 ``FLTrainer`` (Basic FL / CwMed) is the *same* pipeline with the
@@ -32,7 +34,6 @@ is exactly such a third set — registered stages, zero round-loop edits.
 """
 from __future__ import annotations
 
-import time
 from dataclasses import dataclass, field
 from typing import Any, Callable, Dict, List, Optional, Protocol, Set
 
@@ -50,6 +51,7 @@ from repro.core.attacks import ATTACKS
 from repro.core.consensus import CommitteeConsensus, ValidationRecord
 from repro.core.incentive import distribute_rewards
 from repro.fl.client import sample_client_batches
+from repro.tracing import span
 
 
 def _unstack(tree, n: int):
@@ -204,8 +206,10 @@ REGISTRIES: Dict[str, Dict[str, Stage]] = {
 
 STAGE_KINDS = tuple(REGISTRIES)
 
-# keys under which RoundPipeline.run records wall clock in ctx.timings —
-# the schema of BENCH_round.json rows (benchmarks/round_bench.py)
+# the buckets under which both round runners record each stage's host
+# seconds in ctx.timings; a dotted key ``<bucket>.<phase>`` (``stage_span``)
+# times a phase inside its bucket, ``<bucket>.wait`` the host blocked on
+# the device
 STAGE_TIMING_KEYS = (
     "sample", "train", "validate", "pack", "aggregate", "elect", "reward",
 )
@@ -237,11 +241,17 @@ def resolve(kind: str, impl) -> Stage:
     return registry[impl]
 
 
+def stage_span(ctx: RoundContext, key: str):
+    """The ``bflc.<key>`` span, its host seconds added to
+    ``ctx.timings[key]``."""
+    return span(f"bflc.{key}", ctx.timings, key)
+
+
 def _sync_tree(ctx: RoundContext) -> list:
     """Every ctx field a stage may leave as in-flight device work.
 
     The sequential driver blocks on all of these after each stage so
-    BENCH_round buckets measure their own compute: ``cohort_stacked`` /
+    each stage's bucket holds its own compute: ``cohort_stacked`` /
     ``train_inflight`` catch the sharded trainer's async dispatch (which
     used to bleed into the validate bucket), ``cohort_scores`` the
     validator's score matrix, and a tiered round's ``sub_aggregates`` the
@@ -266,7 +276,8 @@ class RoundPipeline:
     validator sets ``ctx.collected`` (k qualified updates — the paper's
     aggregation trigger) or ``max_cohorts`` is hit, then runs
     pack -> aggregate -> elect -> reward once.  Each stage call is timed
-    into ``ctx.timings`` under its stage key."""
+    into ``ctx.timings`` under its stage key, the wait for its device work
+    under ``<key>.wait``."""
 
     sampler: Sampler
     local_trainer: LocalTrainer
@@ -278,16 +289,17 @@ class RoundPipeline:
     max_cohorts: int = 3
 
     def _timed(self, key: str, fn: Callable, ctx: RoundContext) -> None:
-        t0 = time.perf_counter()
-        fn(ctx)
-        # jitted stages return asynchronously — block on the jax-carrying
-        # ctx fields so each stage's compute lands in its own bucket
-        # instead of bleeding into the next stage's first sync point
-        jax.block_until_ready(_sync_tree(ctx))
-        ctx.timings[key] = ctx.timings.get(key, 0.0) + (time.perf_counter() - t0)
+        with stage_span(ctx, key):
+            fn(ctx)
+            # jitted stages return asynchronously — block on the
+            # jax-carrying ctx fields so each stage's compute lands in its
+            # own bucket instead of bleeding into the next stage's first
+            # sync point
+            with stage_span(ctx, f"{key}.wait"):
+                jax.block_until_ready(_sync_tree(ctx))
 
     def run(self, ctx: RoundContext) -> RoundContext:
-        # stage -> timing key: STAGE_TIMING_KEYS, the BENCH_round schema
+        # stage -> timing key: STAGE_TIMING_KEYS
         prepare = getattr(self.validator, "prepare", None)
         if prepare is not None:
             self._timed("validate", prepare, ctx)
@@ -423,14 +435,16 @@ def sample_cohort_batches(ctx: RoundContext):
     multi-device trainers share this so a fixed seed produces the same
     stream (the differential tests compare chain hashes)."""
     cfg, rng = ctx.cfg, ctx.rng
-    pairs = [
-        sample_client_batches(
-            rng, ctx.data.client_images[i], ctx.data.client_labels[i],
-            cfg.local_steps, cfg.local_batch,
-        )
-        for i in ctx.trainers
-    ]
-    return np.stack([p[0] for p in pairs]), np.stack([p[1] for p in pairs])
+    with stage_span(ctx, "train.batches"):
+        pairs = [
+            sample_client_batches(
+                rng, ctx.data.client_images[i], ctx.data.client_labels[i],
+                cfg.local_steps, cfg.local_batch,
+            )
+            for i in ctx.trainers
+        ]
+        return (np.stack([p[0] for p in pairs]),
+                np.stack([p[1] for p in pairs]))
 
 
 def poison_cohort_updates(ctx: RoundContext, updates: List[Any]) -> List[int]:
@@ -464,14 +478,16 @@ class LocalSGDTrainer:
 
     def dispatch(self, ctx: RoundContext) -> None:
         xs, ys = sample_cohort_batches(ctx)
-        ctx.train_inflight = ctx.local_train_fn(ctx.params, xs, ys)
+        with stage_span(ctx, "train.dispatch"):
+            ctx.train_inflight = ctx.local_train_fn(ctx.params, xs, ys)
         ctx.cohort_stacked = None          # single-device: no sharded stack
 
     def finalize(self, ctx: RoundContext) -> None:
         stacked = ctx.train_inflight
         ctx.train_inflight = None
-        updates = _unstack(stacked, len(ctx.trainers))
-        poison_cohort_updates(ctx, updates)
+        with stage_span(ctx, "train.unstack"):
+            updates = _unstack(stacked, len(ctx.trainers))
+            poison_cohort_updates(ctx, updates)
         ctx.cohort_updates = updates
 
     def __call__(self, ctx: RoundContext) -> None:
@@ -502,15 +518,16 @@ class CommitteeValidator:
 
     def prepare(self, ctx: RoundContext) -> None:
         cfg, rng = ctx.cfg, ctx.rng
-        vpairs = [
-            sample_client_batches(
-                rng, ctx.data.client_images[j], ctx.data.client_labels[j],
-                1, cfg.val_batch,
-            )
-            for j in ctx.round_committee
-        ]
-        ctx.val_x = np.stack([p[0][0] for p in vpairs])
-        ctx.val_y = np.stack([p[1][0] for p in vpairs])
+        with stage_span(ctx, "validate.batches"):
+            vpairs = [
+                sample_client_batches(
+                    rng, ctx.data.client_images[j], ctx.data.client_labels[j],
+                    1, cfg.val_batch,
+                )
+                for j in ctx.round_committee
+            ]
+            ctx.val_x = np.stack([p[0][0] for p in vpairs])
+            ctx.val_y = np.stack([p[1][0] for p in vpairs])
         ctx.consensus = CommitteeConsensus(
             ctx.round_committee, accept_threshold=cfg.accept_threshold
         )
@@ -529,7 +546,9 @@ class CommitteeValidator:
     def finalize(self, ctx: RoundContext) -> None:
         cfg, rng = ctx.cfg, ctx.rng
         # gather + drop padding rows (sharded scorers return >= P rows)
-        honest_scores = np.asarray(ctx.cohort_scores)[: len(ctx.cohort_updates)]
+        with stage_span(ctx, "validate.wait"):
+            scores = np.asarray(ctx.cohort_scores)
+        honest_scores = scores[: len(ctx.cohort_updates)]
         ctx.cohort_scores = honest_scores               # (P, Q)
         for i, uploader in enumerate(ctx.trainers):
             row = {}
@@ -661,9 +680,10 @@ def _set_packed(ctx: RoundContext, records: List[ValidationRecord]) -> None:
 def pack_top_k(ctx: RoundContext) -> None:
     """Packs the top-k qualified updates as f32 update blocks."""
     _set_packed(ctx, _select_top_k(ctx))
-    for i, (u, sc) in enumerate(zip(ctx.packed_ids, ctx.packed_scores)):
-        ctx.chain.append_update(ctx.packed_updates[i], u, sc)
-        ctx.manager.nodes[u].score_history.append(sc)
+    with stage_span(ctx, "pack.chain"):
+        for i, (u, sc) in enumerate(zip(ctx.packed_ids, ctx.packed_scores)):
+            ctx.chain.append_update(ctx.packed_updates[i], u, sc)
+            ctx.manager.nodes[u].score_history.append(sc)
 
 
 @register("packer", "top_k_int8")
@@ -684,11 +704,12 @@ def pack_top_k_int8(ctx: RoundContext) -> None:
     else:
         stack, unravel = flatten_updates(ctx.packed_updates)
         q, s, d = quantize_stack(stack)
-    for i, (u, sc) in enumerate(zip(ctx.packed_ids, ctx.packed_scores)):
-        ctx.chain.append_update(
-            {"q": q[i], "scales": s[i], "d": d}, u, sc, encoded=True
-        )
-        ctx.manager.nodes[u].score_history.append(sc)
+    with stage_span(ctx, "pack.chain"):
+        for i, (u, sc) in enumerate(zip(ctx.packed_ids, ctx.packed_scores)):
+            ctx.chain.append_update(
+                {"q": q[i], "scales": s[i], "d": d}, u, sc, encoded=True
+            )
+            ctx.manager.nodes[u].score_history.append(sc)
     ctx.packed_quantized = (q, s, d, unravel)
 
 
@@ -710,7 +731,8 @@ def _commit_aggregate(ctx: RoundContext, agg) -> None:
     ctx.aggregate = agg
     ctx.new_params = apply_update(ctx.params, agg)
     if ctx.chain is not None:
-        ctx.chain.append_model(ctx.new_params, ctx.round + 1)
+        with stage_span(ctx, "aggregate.chain"):
+            ctx.chain.append_model(ctx.new_params, ctx.round + 1)
 
 
 @register("aggregator", "pytree")

@@ -68,6 +68,7 @@ from repro.fl.pipeline import (
     poison_cohort_updates,
     register,
     sample_cohort_batches,
+    stage_span,
 )
 
 
@@ -118,8 +119,9 @@ class ShardedLocalSGDTrainer(LocalSGDTrainer):
         mesh = _require(ctx, "mesh", "local_sgd_sharded")
         ndev = dict(mesh.shape).get("data", mesh.devices.size)
         xs, ys = sample_cohort_batches(ctx)
-        xs, ys, _ = _pad_clients(xs, ys, ndev)
-        stacked = train_fn(ctx.params, xs, ys)
+        with stage_span(ctx, "train.dispatch"):
+            xs, ys, _ = _pad_clients(xs, ys, ndev)
+            stacked = train_fn(ctx.params, xs, ys)
         # the P-sharded update stack (padded rows included) stays on its
         # devices for the sharded validator — committee scoring consumes it
         # with zero relayout.
@@ -133,10 +135,12 @@ class ShardedLocalSGDTrainer(LocalSGDTrainer):
         # stack would make GSPMD replicate their compute per shard
         # (observed: pack/aggregate re-sharding pathology before this
         # gather).
-        host = jax.device_get(ctx.train_inflight)
+        with stage_span(ctx, "train.wait"):
+            host = jax.device_get(ctx.train_inflight)
         ctx.train_inflight = None
-        updates = _unstack(host, len(ctx.trainers))  # padded rows dropped
-        poison_cohort_updates(ctx, updates)
+        with stage_span(ctx, "train.unstack"):
+            updates = _unstack(host, len(ctx.trainers))  # padded rows dropped
+            poison_cohort_updates(ctx, updates)
         ctx.cohort_updates = updates
 
 
@@ -187,12 +191,13 @@ def pack_top_k_int8_sharded(ctx: RoundContext) -> None:
     # inside the loop would pay a cross-device gather + host transfer per
     # blob (the digest reads the bytes anyway); the aggregator still gets
     # the sharded (q, s) below
-    qh, sh = jax.device_get((q, s))
-    for i, (u, sc) in enumerate(zip(ctx.packed_ids, ctx.packed_scores)):
-        ctx.chain.append_update(
-            {"q": qh[i], "scales": sh[i], "d": d}, u, sc, encoded=True
-        )
-        ctx.manager.nodes[u].score_history.append(sc)
+    with stage_span(ctx, "pack.chain"):
+        qh, sh = jax.device_get((q, s))
+        for i, (u, sc) in enumerate(zip(ctx.packed_ids, ctx.packed_scores)):
+            ctx.chain.append_update(
+                {"q": qh[i], "scales": sh[i], "d": d}, u, sc, encoded=True
+            )
+            ctx.manager.nodes[u].score_history.append(sc)
     ctx.packed_quantized = (q, s, d, unravel)
 
 
@@ -276,5 +281,7 @@ def aggregate_fused_int8_sharded(ctx: RoundContext) -> None:
     # P x Q scoring) is keyed on replicated params — leaving them
     # D-sharded re-shards each of those programs instead (same pathology
     # as the trainer's gather above)
-    flat = np.asarray(agg_fn(q, s, w)[:d])
+    out = agg_fn(q, s, w)[:d]
+    with stage_span(ctx, "aggregate.wait"):
+        flat = np.asarray(out)
     _commit_aggregate(ctx, unravel(flat))

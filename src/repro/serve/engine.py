@@ -11,6 +11,10 @@ finish detection is count-based, so the hot loop never blocks on token
 values: each tick's token vector is fetched one tick late, while the next
 tick is already in flight on device.
 
+Each phase of the loop runs inside an ``engine.<phase>`` span
+(``repro.tracing``: admit, prefill, insert, tick, fetch, swap, idle); the
+run's host seconds per span come back as ``ServeReport.host_s``.
+
 The engine also watches a ``ParamSource`` (live chain or checkpoint
 directory — ``repro.serve.params``) and hot-swaps the whole parameter pytree
 at a tick boundary when a new round commits a model block.  In-flight
@@ -37,6 +41,7 @@ from repro.models.transformer import Batch
 from repro.serve.scheduler import FifoScheduler
 from repro.serve.slots import Request, RequestResult, SlotTable
 from repro.serve.trace import aggregate
+from repro.tracing import span
 
 
 # ----------------------------------------------------------------------------
@@ -107,12 +112,18 @@ class ServeReport:
     occupancy: float                              # mean active-slot fraction
     swaps: List[Dict[str, Any]]
     policy: str
+    # host seconds inside each ``engine.<phase>`` span over the run
+    host_s: Dict[str, float] = field(default_factory=dict)
 
     def metrics(self) -> Dict[str, float]:
-        return aggregate(
+        out = aggregate(
             self.results, wall_s=self.wall_s, ticks=self.ticks,
             occupancy=self.occupancy, swaps=len(self.swaps),
         )
+        per_tick = max(1, self.ticks)
+        for name, secs in sorted(self.host_s.items()):
+            out[f"{name}_ms_per_tick"] = round(secs * 1e3 / per_tick, 4)
+        return out
 
     def by_rid(self) -> Dict[int, RequestResult]:
         return {r.rid: r for r in self.results}
@@ -183,6 +194,8 @@ class ServeEngine:
             return tokens, positions, cache
 
         self._insert = jax.jit(insert, donate_argnums=(0, 2))
+        # host seconds per ``engine.<phase>`` span of the current run
+        self._host_s: Dict[str, float] = {}
 
     # ------------------------------------------------------------------
     def _make_prompt_batch(self, prompt: np.ndarray) -> Batch:
@@ -229,15 +242,17 @@ class ServeEngine:
     def _poll_swap(self, tick_idx: int, clock, swaps: List[dict]) -> None:
         if self.source is None:
             return
-        got = self.source.poll()
-        if got is None:
-            return
-        ver, new_params = got
-        # cast onto the serving dtype layout; structure must match, which a
-        # chain model block / checkpoint of the same arch guarantees
-        self.params = jax.tree.map(
-            lambda n, o: jnp.asarray(n, o.dtype), new_params, self.params
-        )
+        with span("engine.swap", self._host_s):
+            got = self.source.poll()
+            if got is None:
+                return
+            ver, new_params = got
+            # cast onto the serving dtype layout; structure must match,
+            # which a chain model block / checkpoint of the same arch
+            # guarantees
+            self.params = jax.tree.map(
+                lambda n, o: jnp.asarray(n, o.dtype), new_params, self.params
+            )
         self.version = ver
         swaps.append({"round": int(ver), "tick": tick_idx,
                       "t": round(clock.now(), 6)})
@@ -249,7 +264,8 @@ class ServeEngine:
         overlaps with the next tick already running on device."""
         while pending and (force or len(pending) > 1):
             rec = pending.popleft()
-            toks = np.asarray(rec.tok)
+            with span("engine.fetch", self._host_s):
+                toks = np.asarray(rec.tok)
             now = clock.now()
             for rid, row, first, last in rec.deliveries:
                 r = results[rid]
@@ -298,6 +314,7 @@ class ServeEngine:
         swaps: List[dict] = []
         tick_idx = 0
         active_ticks = 0          # sum of active slots over all ticks
+        host_s = self._host_s = {}
         t_start = time.perf_counter()
 
         while not (sched.exhausted and table.all_free and not pending):
@@ -306,31 +323,35 @@ class ServeEngine:
 
             # ---- admissions (prefill-into-slot) --------------------------
             for b, req in sched.admissions(table, clock.now()):
-                res = results[req.rid]
-                res.admitted = clock.now()
-                res.version_admitted = self.version
-                batch = self._make_prompt_batch(req.prompt)
-                tok, slot_cache = self._prefill(self.params, batch)
-                one_shot = req.max_new == 1
-                pending.append(_Pending(
-                    tok=tok,
-                    deliveries=[(req.rid, 0, True, one_shot)],
-                    version=self.version,
-                ))
-                if not one_shot:
-                    tokens, positions, cache = self._insert(
-                        cache, tokens, positions, slot_cache, tok,
-                        jnp.asarray(req.prompt_len, jnp.int32),
-                        jnp.asarray(b, jnp.int32),
-                    )
-                    table.occupy(b, req.rid, req.max_new - 1)
+                with span("engine.admit", host_s):
+                    res = results[req.rid]
+                    res.admitted = clock.now()
+                    res.version_admitted = self.version
+                    batch = self._make_prompt_batch(req.prompt)
+                    with span("engine.prefill", host_s):
+                        tok, slot_cache = self._prefill(self.params, batch)
+                    one_shot = req.max_new == 1
+                    pending.append(_Pending(
+                        tok=tok,
+                        deliveries=[(req.rid, 0, True, one_shot)],
+                        version=self.version,
+                    ))
+                    if not one_shot:
+                        with span("engine.insert", host_s):
+                            tokens, positions, cache = self._insert(
+                                cache, tokens, positions, slot_cache, tok,
+                                jnp.asarray(req.prompt_len, jnp.int32),
+                                jnp.asarray(b, jnp.int32),
+                            )
+                        table.occupy(b, req.rid, req.max_new - 1)
 
             # ---- one fused decode tick over the whole slot batch ---------
             if table.num_active:
                 rids = table.active_snapshot()
-                tokens, positions, cache = self._tick(
-                    self.params, tokens, positions, cache
-                )
+                with span("engine.tick", host_s):
+                    tokens, positions, cache = self._tick(
+                        self.params, tokens, positions, cache
+                    )
                 done_slots = table.decrement_active()
                 done_set = set(done_slots)
                 deliveries = [
@@ -354,7 +375,8 @@ class ServeEngine:
                 self._drain(pending, results, clock, force=True)
                 na = sched.next_arrival()
                 if na is not None:
-                    clock.advance_to(na)
+                    with span("engine.idle", host_s):
+                        clock.advance_to(na)
 
         self._drain(pending, results, clock, force=True)
         wall = time.perf_counter() - t_start
@@ -362,4 +384,5 @@ class ServeEngine:
                      if tick_idx else 0.0)
         ordered = [results[r.rid] for r in sorted(requests, key=lambda q: q.rid)]
         return ServeReport(results=ordered, wall_s=wall, ticks=tick_idx,
-                           occupancy=occupancy, swaps=swaps, policy=policy)
+                           occupancy=occupancy, swaps=swaps, policy=policy,
+                           host_s=dict(host_s))
