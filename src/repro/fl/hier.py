@@ -119,8 +119,8 @@ def _tree_nbytes(tree) -> int:
 
 def _slice_stack_nbytes(ctx: RoundContext) -> int:
     """Bytes of the update stack currently buffered for this slice (the
-    device-resident padded stack when the sharded trainer ran, else the
-    host-side update list)."""
+    trainer's stack when it handed one on, padded when sharded, else the
+    update list)."""
     if ctx.cohort_stacked is not None:
         return _tree_nbytes(ctx.cohort_stacked)
     if not ctx.cohort_updates:

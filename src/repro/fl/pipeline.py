@@ -58,6 +58,11 @@ def _unstack(tree, n: int):
     return [jax.tree.map(lambda x: x[i], tree) for i in range(n)]
 
 
+# ``_unstack`` of a device stack as one compiled call: eagerly it
+# dispatches an index, a slice and a squeeze per client and leaf
+_split = jax.jit(_unstack, static_argnums=1)
+
+
 def _stack(trees):
     return jax.tree.map(lambda *xs: jnp.stack(xs), *trees)
 
@@ -114,7 +119,7 @@ class RoundContext:
     cohort: int = 0
     trainers: List[int] = field(default_factory=list)
     cohort_updates: List[Any] = field(default_factory=list)
-    cohort_stacked: Any = None             # trainer's P-padded update stack
+    cohort_stacked: Any = None             # trainer's update stack (rows >= P)
     cohort_poisoned: List[int] = field(default_factory=list)
     cohort_scores: Any = None              # validator's (P, Q) score matrix
     train_inflight: Any = None             # trainer's dispatched device stack
@@ -451,8 +456,8 @@ def poison_cohort_updates(ctx: RoundContext, updates: List[Any]) -> List[int]:
     """Per-node attack injection for malicious trainers (in place).
 
     Returns the poisoned indices (also recorded in ``ctx.cohort_poisoned``)
-    so sharded validators know whether the trainer's device-resident update
-    stack still matches the host-side update list."""
+    so the scorers know whether the trainer's update stack still matches
+    the update list (``cohort_stack``)."""
     cfg, rng = ctx.cfg, ctx.rng
     attack = ATTACKS[cfg.attack]
     poisoned = []
@@ -471,7 +476,8 @@ class LocalSGDTrainer:
     attack injection for malicious trainers.
 
     Split into ``dispatch`` (host rng batch draws + async XLA launch into
-    ``ctx.train_inflight``) and ``finalize`` (unstack + attack injection)
+    ``ctx.train_inflight``, the stack also left on ``ctx.cohort_stacked``
+    for the scorer) and ``finalize`` (per-client split + attack injection)
     so the async engine can overlap cohort t+1's device compute with
     cohort t's host-side validate/pack work; ``__call__`` runs both
     back-to-back — the sequential engine is unchanged, op for op."""
@@ -480,13 +486,13 @@ class LocalSGDTrainer:
         xs, ys = sample_cohort_batches(ctx)
         with stage_span(ctx, "train.dispatch"):
             ctx.train_inflight = ctx.local_train_fn(ctx.params, xs, ys)
-        ctx.cohort_stacked = None          # single-device: no sharded stack
+        ctx.cohort_stacked = ctx.train_inflight
 
     def finalize(self, ctx: RoundContext) -> None:
         stacked = ctx.train_inflight
         ctx.train_inflight = None
         with stage_span(ctx, "train.unstack"):
-            updates = _unstack(stacked, len(ctx.trainers))
+            updates = _split(stacked, len(ctx.trainers))
             poison_cohort_updates(ctx, updates)
         ctx.cohort_updates = updates
 
@@ -496,6 +502,17 @@ class LocalSGDTrainer:
 
 
 train_local_sgd = register("local_trainer", "local_sgd")(LocalSGDTrainer())
+
+
+def cohort_stack(ctx: RoundContext):
+    """The cohort's update stack for a score program: the trainer's own
+    while no update was poisoned (it then holds the list's values bit for
+    bit), else ``ctx.cohort_updates`` re-stacked in a ``validate.restack``
+    span."""
+    if ctx.cohort_stacked is not None and not ctx.cohort_poisoned:
+        return ctx.cohort_stacked
+    with stage_span(ctx, "validate.restack"):
+        return _stack(ctx.cohort_updates)
 
 
 class CommitteeValidator:
@@ -537,7 +554,7 @@ class CommitteeValidator:
         """The (rows >= P, Q) accuracy matrix of this cohort's candidates,
         as the score program's (possibly still in-flight) device result."""
         return ctx.score_matrix_fn(
-            ctx.params, _stack(ctx.cohort_updates), ctx.val_x, ctx.val_y
+            ctx.params, cohort_stack(ctx), ctx.val_x, ctx.val_y
         )
 
     def dispatch(self, ctx: RoundContext) -> None:

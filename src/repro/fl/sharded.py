@@ -61,10 +61,10 @@ from repro.fl.pipeline import (
     _select_top_k,
     _set_packed,
     _commit_aggregate,
-    _stack,
     _unstack,
     cache_row_quant,
     cached_row_stack,
+    cohort_stack,
     poison_cohort_updates,
     register,
     sample_cohort_batches,
@@ -201,6 +201,17 @@ def pack_top_k_int8_sharded(ctx: RoundContext) -> None:
     ctx.packed_quantized = (q, s, d, unravel)
 
 
+def _mesh_cohort_stack(ctx: RoundContext, stage: str):
+    """``cohort_stack`` with its rows padded to a multiple of the mesh's
+    data axis.  The sharded trainer's stack is padded and P-sharded
+    already, so it is scored in place with no relayout; the programs
+    flatten it themselves where they need to."""
+    mesh = _require(ctx, "mesh", stage)
+    ndev = dict(mesh.shape).get("data", mesh.devices.size)
+    stacked = cohort_stack(ctx)
+    return _pad_rows(stacked, jax.tree.leaves(stacked)[0].shape[0], ndev)
+
+
 class ShardedCommitteeValidator(CommitteeValidator):
     """(3, sharded) the P x Q committee score matrix shard_mapped over the
     mesh's data axis — each device scores its P-shard of candidates; only
@@ -210,16 +221,7 @@ class ShardedCommitteeValidator(CommitteeValidator):
 
     def _scores_device(self, ctx: RoundContext):
         score_fn = _require(ctx, "sharded_score_fn", "committee_sharded")
-        mesh = _require(ctx, "mesh", "committee_sharded")
-        ndev = dict(mesh.shape).get("data", mesh.devices.size)
-        n = len(ctx.cohort_updates)
-        if ctx.cohort_stacked is not None and not ctx.cohort_poisoned:
-            # the trainer's update stack is still bit-identical to the
-            # host-side update list AND already P-sharded on this mesh:
-            # score it in place — no host round-trip, no relayout
-            stacked = ctx.cohort_stacked
-        else:
-            stacked = _pad_rows(_stack(ctx.cohort_updates), n, ndev)
+        stacked = _mesh_cohort_stack(ctx, "committee_sharded")
         return score_fn(ctx.params, stacked, ctx.val_x, ctx.val_y)
 
 
@@ -238,17 +240,7 @@ class Int8ShardedCommitteeValidator(CommitteeValidator):
         score_fn = _require(
             ctx, "sharded_int8_score_fn", "committee_int8_sharded"
         )
-        mesh = _require(ctx, "mesh", "committee_int8_sharded")
-        ndev = dict(mesh.shape).get("data", mesh.devices.size)
-        n = len(ctx.cohort_updates)
-        if ctx.cohort_stacked is not None and not ctx.cohort_poisoned:
-            # the trainer's device-resident stack is still bit-identical
-            # to the host-side update list AND already P-sharded on this
-            # mesh: the scorer flattens it in-program — no host flatten,
-            # no relayout
-            stacked = ctx.cohort_stacked
-        else:
-            stacked = _pad_rows(_stack(ctx.cohort_updates), n, ndev)
+        stacked = _mesh_cohort_stack(ctx, "committee_int8_sharded")
         scores, q, s = score_fn(
             ctx.params, stacked, ctx.val_x, ctx.val_y
         )
