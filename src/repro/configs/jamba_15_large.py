@@ -1,21 +1,14 @@
 """Jamba-1.5-Large 398B [arXiv:2403.19887] — hybrid Mamba+attention 1:7, MoE.
 
 72L d_model=8192 64H (kv=8) d_ff=24576 vocab=65536, MoE 16 experts top-2.
-Unit of 8 layers: 1 attention + 7 Mamba; MoE MLP on every other layer
-(4 MoE per unit).  Hybrid: long_500k runs (bounded attention fraction).
+Unit of 8 layers (attn_layer_period 8, offset 4): attention at layer 4, Mamba
+elsewhere; MoE MLP on the odd layers (expert_layer_period 2, offset 1).  No
+positional encoding: Jamba's attention has no RoPE.  Hybrid: long_500k runs
+(bounded attention fraction).
 """
-from repro.models.config import LayerSpec, ModelConfig
+from repro.models.config import ModelConfig, periodic_unit
 
 ARCH_ID = "jamba-1.5-large-398b"
-
-
-def _unit():
-    layers = []
-    for i in range(8):
-        mixer = "attn" if i == 0 else "mamba"
-        mlp = "moe" if i % 2 == 1 else "dense"
-        layers.append(LayerSpec(mixer=mixer, mlp=mlp))
-    return tuple(layers)
 
 
 def get_config(**kw) -> ModelConfig:
@@ -24,7 +17,7 @@ def get_config(**kw) -> ModelConfig:
         arch_type="hybrid",
         d_model=8192,
         vocab_size=65536,
-        unit=_unit(),
+        unit=periodic_unit(8, 4, 2, 1, moe=True),
         num_units=9,
         num_heads=64,
         num_kv_heads=8,
@@ -35,6 +28,7 @@ def get_config(**kw) -> ModelConfig:
         mamba_d_state=16,
         mamba_d_conv=4,
         mamba_expand=2,
+        rope="none",
         citation="arXiv:2403.19887",
     )
     base.update(kw)
@@ -42,10 +36,7 @@ def get_config(**kw) -> ModelConfig:
 
 
 def smoke_config() -> ModelConfig:
-    unit = (
-        LayerSpec(mixer="attn", mlp="dense"),
-        LayerSpec(mixer="mamba", mlp="moe"),
-    )
-    return get_config(unit=unit, num_units=1, d_model=128, num_heads=4,
-                      num_kv_heads=2, d_ff=256, moe_d_ff=256, vocab_size=1024,
-                      num_experts=4, num_experts_per_tok=2, mamba_d_state=8)
+    return get_config(unit=periodic_unit(2, 0, 2, 1, moe=True), num_units=1,
+                      d_model=128, num_heads=4, num_kv_heads=2, d_ff=256,
+                      moe_d_ff=256, vocab_size=1024, num_experts=4,
+                      num_experts_per_tok=2, mamba_d_state=8)

@@ -8,6 +8,7 @@ from repro.models.config import ModelConfig
 from repro.configs import (
     gemma3_4b,
     hubert_xlarge,
+    jamba2_3b,
     jamba_15_large,
     mixtral_8x7b,
     olmo_1b,
@@ -29,6 +30,7 @@ _MODULES = {
         qwen3_moe_30b,
         phi4_mini,
         jamba_15_large,
+        jamba2_3b,
         gemma3_4b,
         qwen2_vl_7b,
     )

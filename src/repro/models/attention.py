@@ -257,10 +257,18 @@ def attention_forward(
             else:                          # shard the q-block dim instead
                 q_block = pick_q_block(S, ctx.model_size)
                 block_spec = P(ctx.dp, ctx.model_axis, None, None, None, None)
+        # the flash blocks tile the sequence: pad it to whole blocks with
+        # keys at position -1 (masked) and drop the pad queries' rows
+        pad = (-S) % KV_BLOCK
+        if pad:
+            seq_pad = lambda t: jnp.pad(
+                t, ((0, 0), (0, pad)) + ((0, 0),) * (t.ndim - 2))
+            q, k_e, v_e = seq_pad(q), seq_pad(k_e), seq_pad(v_e)
+            pos2d = jnp.pad(pos2d, ((0, 0), (0, pad)), constant_values=-1)
         out = flash_attention(
             q, k_e, v_e, pos2d, pos2d, causal, window, q_block,
             block_spec, mesh,
-        )
+        )[:, :S]
     B, Sq = out.shape[0], out.shape[1]
     out = out.reshape(B, Sq, -1) @ params["wo"]
     if return_kv:

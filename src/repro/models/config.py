@@ -11,6 +11,7 @@ depth — essential for the 33-combination multi-pod dry-run.
 from __future__ import annotations
 
 import dataclasses
+import math
 from dataclasses import dataclass, field
 from typing import Optional, Tuple
 
@@ -161,3 +162,20 @@ def dense_unit(n: int = 1, mixer: str = ATTN) -> Tuple[LayerSpec, ...]:
 
 def moe_unit(n: int = 1, mixer: str = ATTN) -> Tuple[LayerSpec, ...]:
     return tuple(LayerSpec(mixer=mixer, mlp=MLP_MOE) for _ in range(n))
+
+
+def periodic_unit(attn_period: int, attn_offset: int, expert_period: int,
+                  expert_offset: int, moe: bool) -> Tuple[LayerSpec, ...]:
+    """One period of a Jamba-style stack, as its published config states it:
+    layer i mixes with attention where ``i % attn_period == attn_offset`` and
+    with Mamba elsewhere; its MLP is MoE where ``i % expert_period ==
+    expert_offset`` and ``moe`` holds (more than one expert), else dense."""
+    n = math.lcm(attn_period, expert_period)
+    return tuple(
+        LayerSpec(
+            mixer=ATTN if i % attn_period == attn_offset else MAMBA,
+            mlp=MLP_MOE if moe and i % expert_period == expert_offset
+            else MLP_DENSE,
+        )
+        for i in range(n)
+    )

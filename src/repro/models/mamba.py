@@ -2,7 +2,9 @@
 
 Training/prefill runs a *chunked* scan: an outer ``lax.scan`` over chunks of
 ``CHUNK`` tokens (rematerialized, so backward keeps only per-chunk states)
-with an inner exact sequential scan.  Decode is the exact single-step
+with an inner exact sequential scan, under the ``mamba.scan`` named scope.
+A sequence that is not a multiple of ``CHUNK`` is padded with steps that
+carry the state unchanged.  Decode is the exact single-step
 recurrence with a (conv_state, ssm_state) cache.
 
 Recurrence (per channel c of d_inner, per state dim n of d_state):
@@ -105,6 +107,14 @@ def _scan_chunk(params_A, h0, u, dt, Bm, Cm):
     return h, ys.swapaxes(0, 1)                            # (B,L,din)
 
 
+def _time_pad(t, pad: int):
+    """(B,S,C) -> (B,S+pad,C) with zero steps after the sequence.  A pad step
+    has dt = 0, so exp(dt*A) = 1 and dt*B*u = 0 carry the state through it
+    unchanged: the state handed to decode is exact for any S.  (dt computed
+    there, softplus(dt_bias), would decay it.)"""
+    return jnp.pad(t, ((0, 0), (0, pad), (0, 0))) if pad else t
+
+
 def mamba_forward(params, x, cfg: ModelConfig, state=None):
     """x: (B,S,D) -> (out, new_state).
 
@@ -135,11 +145,7 @@ def mamba_forward(params, x, cfg: ModelConfig, state=None):
     A = -jnp.exp(params["A_log"])                          # (din,ds)
 
     pad = (-S) % CHUNK
-    if pad:
-        padt = lambda t: jnp.pad(t, ((0, 0), (0, pad), (0, 0)))
-        u_act_p, dt_p, Bm_p, Cm_p = map(padt, (u_act, dt, Bm, Cm))
-    else:
-        u_act_p, dt_p, Bm_p, Cm_p = u_act, dt, Bm, Cm
+    u_act_p, dt_p, Bm_p, Cm_p = (_time_pad(t, pad) for t in (u_act, dt, Bm, Cm))
     n = u_act_p.shape[1] // CHUNK
 
     reshape = lambda t: t.reshape(B, n, CHUNK, t.shape[-1]).swapaxes(0, 1)
@@ -149,19 +155,16 @@ def mamba_forward(params, x, cfg: ModelConfig, state=None):
         uc, dtc, bc, cc = xs
         return _scan_chunk(A, h, uc, dtc, bc, cc)
 
-    h_final, ys = jax.lax.scan(
-        chunk_body,
-        ssm_prev,
-        (reshape(u_act_p), reshape(dt_p), reshape(Bm_p), reshape(Cm_p)),
-    )
+    with jax.named_scope("mamba.scan"):
+        h_final, ys = jax.lax.scan(
+            chunk_body,
+            ssm_prev,
+            (reshape(u_act_p), reshape(dt_p), reshape(Bm_p), reshape(Cm_p)),
+        )
     y = ys.swapaxes(0, 1).reshape(B, n * CHUNK, din)[:, :S]
     y = y + u_act * params["D"].astype(jnp.float32)
     out = (y.astype(x.dtype) * jax.nn.silu(z)) @ params["out_proj"]
 
-    # note: with padding, h_final includes pad steps where dt=0 -> exp(0)=1,
-    # dbu=0 -> state unchanged.  (softplus(0 @ W + bias) != 0, but u_pad=0
-    # makes dbu=0; da = exp(dt*A) < 1 decays state slightly on pad steps —
-    # acceptable for smoke shapes; production shapes are CHUNK-aligned.)
     new_state = {
         "conv": u_pad[:, S : S + dc - 1, :] if dc > 1 else conv_prev,
         "ssm": h_final,
@@ -185,7 +188,8 @@ def mamba_step(params, x, cfg: ModelConfig, state):
 
     dt, Bm, Cm = _ssm_inputs(params, u_act[:, None].astype(x.dtype), cfg)
     A = -jnp.exp(params["A_log"])
-    h, y = _ssm_step(state["ssm"], (u_act, dt[:, 0], Bm[:, 0], Cm[:, 0]), A)
+    with jax.named_scope("mamba.step"):
+        h, y = _ssm_step(state["ssm"], (u_act, dt[:, 0], Bm[:, 0], Cm[:, 0]), A)
     y = y + u_act * params["D"].astype(jnp.float32)
     out = (y.astype(x.dtype) * jax.nn.silu(z))[:, None] @ params["out_proj"]
     return out, {"conv": window[:, 1:], "ssm": h}

@@ -100,6 +100,7 @@ def test_full_config_matches_spec(arch):
         "qwen3-moe-30b-a3b": (48, 2048, 32, 4, 768, 151936),
         "phi4-mini-3.8b": (32, 3072, 24, 8, 8192, 200064),
         "jamba-1.5-large-398b": (72, 8192, 64, 8, 24576, 65536),
+        "jamba2-3b": (28, 2560, 20, 1, 8192, 65536),
         "gemma3-4b": (34, 2560, 8, 4, 10240, 262144),
         "qwen2-vl-7b": (28, 3584, 28, 4, 18944, 152064),
     }[arch]

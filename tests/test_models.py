@@ -71,12 +71,87 @@ def test_hybrid_decode_consistency():
     assert decode_matches_forward(cfg, params, atol=5e-2)
 
 
+def _mamba_exact(p, x, cfg):
+    """The recurrence token by token (``mamba_step``), from a zero state."""
+    from repro.models import mamba
+
+    state = mamba.init_mamba_state(cfg, x.shape[0], jnp.float32)
+    outs = []
+    for t in range(x.shape[1]):
+        o, state = mamba.mamba_step(p, x[:, t:t + 1], cfg, state)
+        outs.append(o)
+    return jnp.concatenate(outs, axis=1), state
+
+
+def _mamba_case():
+    from repro.models import mamba
+
+    cfg = ModelConfig(name="m", arch_type="hybrid", d_model=32,
+                      vocab_size=97, unit=(LayerSpec(mixer="mamba"),),
+                      num_units=1, d_ff=64, mamba_d_state=8)
+    p = mamba.init_mamba(KEY, cfg, jnp.float32)
+    # dt well away from 0, so a pad step that ran the recurrence would show
+    p["dt_bias"] = jnp.full_like(p["dt_bias"], 0.5)
+    return cfg, p
+
+
+@pytest.mark.parametrize("S", [37, 100])
+def test_mamba_forward_exact_off_chunk(S):
+    """A prompt that is not a multiple of CHUNK: the chunked scan's output
+    and the state it hands to decode equal the token-by-token recurrence."""
+    from repro.models import mamba
+
+    assert S % mamba.CHUNK
+    cfg, p = _mamba_case()
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, S, 32))
+    out, state = mamba.mamba_forward(p, x, cfg)
+    ref_out, ref_state = _mamba_exact(p, x, cfg)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref_out),
+                               atol=2e-5)
+    for k in ("conv", "ssm"):
+        np.testing.assert_allclose(np.asarray(state[k]),
+                                   np.asarray(ref_state[k]), atol=2e-6)
+
+
+def test_mamba_pad_steps_that_run_the_recurrence_are_caught(monkeypatch):
+    """The comparison above fails when the pad steps advance the state (the
+    last step's inputs repeated on them)."""
+    from repro.models import mamba
+
+    monkeypatch.setattr(mamba, "_time_pad", lambda t, pad: jnp.pad(
+        t, ((0, 0), (0, pad), (0, 0)), mode="edge") if pad else t)
+    cfg, p = _mamba_case()
+    x = jax.random.normal(jax.random.PRNGKey(1), (2, 37, 32))
+    _, state = mamba.mamba_forward(p, x, cfg)
+    _, ref_state = _mamba_exact(p, x, cfg)
+    assert float(jnp.abs(state["ssm"] - ref_state["ssm"]).max()) > 1e-3
+
+
 def test_chunked_attention_equals_dense():
     from repro.models import attention as attn
 
     cfg = tiny_dense()
     params = init_model(KEY, cfg)
     b = lm_batch(KEY, cfg, 2, 2048)
+    ref, _ = forward(params, cfg, b)
+    old = attn.DENSE_MAX
+    try:
+        attn.DENSE_MAX = 256
+        out, _ = forward(params, cfg, b)
+    finally:
+        attn.DENSE_MAX = old
+    np.testing.assert_allclose(np.asarray(ref), np.asarray(out), atol=5e-4)
+
+
+def test_flash_path_pads_off_block_lengths():
+    """A prompt longer than DENSE_MAX that is no multiple of the flash
+    blocks (a 4000-token prefill): padded keys are masked, pad rows dropped;
+    one KV head under four query heads, no RoPE, as Jamba's attention."""
+    from repro.models import attention as attn
+
+    cfg = tiny_dense(num_kv_heads=1, rope="none")
+    params = init_model(KEY, cfg)
+    b = lm_batch(KEY, cfg, 1, 600)
     ref, _ = forward(params, cfg, b)
     old = attn.DENSE_MAX
     try:
