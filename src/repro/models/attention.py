@@ -276,23 +276,16 @@ def attention_forward(
     return out
 
 
-def attention_decode(
+def attention_decode_qkv(
     params: dict,
     x: jnp.ndarray,            # (B,1,D)
     position: jnp.ndarray,     # (B,) int32 absolute position of the new token
-    cache_k: jnp.ndarray,      # (B,Sc,Kv,Dh)  rotated keys
-    cache_v: jnp.ndarray,      # (B,Sc,Kv,Dh)
-    cache_pos: jnp.ndarray,    # (B,Sc) absolute position per slot (-1 invalid)
     cfg: ModelConfig,
-    mixer: str,
     mrope_position: Optional[jnp.ndarray] = None,   # (3,B,1) for mrope
-) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray, jnp.ndarray]:
-    """Single-token decode against a (possibly ring-buffer) KV cache.
-
-    Returns (out, new_cache_k, new_cache_v, new_cache_pos).
-    Keys are stored rotated, so the cache never needs re-rotation.
-    Sliding-window layers use a ring buffer: slot = position % window.
-    """
+) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Projects one new token per slot: returns q (B,1,H,Dh) and k, v
+    (B,1,Kv,Dh), q and k rotated.  Keys are stored rotated, so the cache
+    never needs re-rotation."""
     q, k, v = _project_qkv(params, x, cfg)
     if cfg.rope == "mrope":
         rp = (
@@ -305,19 +298,48 @@ def attention_decode(
     elif cfg.rope != "none":
         q = apply_rope(q, position[:, None], cfg.rope_theta)
         k = apply_rope(k, position[:, None], cfg.rope_theta)
+    return q, k, v
 
-    Sc = cache_k.shape[1]
+
+def write_decode_kv(
+    cache: dict,               # {"k", "v": (..., B,Sc,Kv,Dh), "pos": (..., B,Sc)}
+    k: jnp.ndarray,            # (B,1,Kv,Dh) rotated
+    v: jnp.ndarray,            # (B,1,Kv,Dh)
+    position: jnp.ndarray,     # (B,)
+    unit=None,
+) -> dict:
+    """Scatters each slot's new K/V row and its position into the cache.
+
+    The leaves are one layer's ``(B, Sc, ...)`` arrays, or with ``unit`` the
+    stacked ``(num_units, B, Sc, ...)`` arrays of the decode scan's carry,
+    written at ``[unit]``: one row per slot, whatever the stack's size.
+    Sliding-window layers use a ring buffer: slot = position % Sc.  For
+    full-attention layers Sc == max_len, so the slot is the position.
+    """
+    slot = position % cache["pos"].shape[-1]
+    idx = (jnp.arange(position.shape[0]), slot)
+    if unit is not None:
+        idx = (unit,) + idx
+    return {
+        "k": cache["k"].at[idx].set(k[:, 0]),
+        "v": cache["v"].at[idx].set(v[:, 0]),
+        "pos": cache["pos"].at[idx].set(position),
+    }
+
+
+def attention_decode_attend(
+    params: dict,
+    q: jnp.ndarray,            # (B,1,H,Dh) rotated
+    position: jnp.ndarray,     # (B,)
+    cache_k: jnp.ndarray,      # (B,Sc,Kv,Dh) rotated keys, the new row written
+    cache_v: jnp.ndarray,      # (B,Sc,Kv,Dh)
+    cache_pos: jnp.ndarray,    # (B,Sc) absolute position per slot (-1 invalid)
+    cfg: ModelConfig,
+    mixer: str,
+) -> jnp.ndarray:
+    """Single-token attention over a (possibly ring-buffer) KV cache that
+    already holds the new token's row: returns the mixer output (B,1,D)."""
     window = cfg.sliding_window if is_windowed(mixer) else 0
-    # Ring-buffer slot.  For full-attention layers Sc == max_len so this is
-    # just ``position``; for windowed layers it wraps around the window.
-    slot = position % Sc
-
-    # write the new K/V/pos into the per-batch slot
-    b_idx = jnp.arange(x.shape[0])
-    cache_k = cache_k.at[b_idx, slot].set(k[:, 0])
-    cache_v = cache_v.at[b_idx, slot].set(v[:, 0])
-    cache_pos = cache_pos.at[b_idx, slot].set(position)
-
     q_pos = position[:, None]                       # (B,1)
     # q_len == 1: dense attention is O(B*H*Skv) — no S^2 blowup — and the
     # softmax reduction over a seq-sharded cache lowers to small psums
@@ -326,5 +348,4 @@ def attention_decode(
     mask = _pair_mask(q_pos, cache_pos, causal=cfg.causal, window=window)
     out = _dense_attention(q, cache_k, cache_v, mask, cfg.attn_logit_softcap)
     B = out.shape[0]
-    out = out.reshape(B, 1, -1) @ params["wo"]
-    return out, cache_k, cache_v, cache_pos
+    return out.reshape(B, 1, -1) @ params["wo"]

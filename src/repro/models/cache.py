@@ -1,8 +1,10 @@
 """Decode-cache containers for the heterogeneous layer stack.
 
 A model cache is ``{"units": stacked_pytree, "tail": (per-layer, ...)}`` where
-the stacked pytree has a leading ``num_units`` axis so the decode step can
-``lax.scan`` over (unit_params, unit_cache) together.
+the stacked pytree has a leading ``num_units`` axis.  The decode step
+``lax.scan``s over the unit params and carries the whole stack: each layer
+writes its new rows or state into it at the unit's index, so a donated cache
+is updated in place.
 
 Per-layer cache by mixer kind:
   attn / attn_global : {"k": (B, max_len, Kv, hd), "v": ..., "pos": (B, max_len)}
@@ -51,8 +53,10 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int, dtype) -> dict:
     unit = tuple(
         init_layer_cache(cfg, spec, batch, max_len, dtype) for spec in cfg.unit
     )
+    # the broadcast already makes a fresh buffer: a copy of it would hold
+    # the whole cache twice while it is made
     stacked = jax.tree.map(
-        lambda x: jnp.broadcast_to(x[None], (cfg.num_units,) + x.shape).copy()
+        lambda x: jnp.broadcast_to(x[None], (cfg.num_units,) + x.shape)
         if cfg.num_units
         else x,
         unit,

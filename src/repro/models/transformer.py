@@ -20,9 +20,11 @@ import jax.numpy as jnp
 from repro.models import mamba as mamba_mod
 from repro.models import rwkv6 as rwkv_mod
 from repro.models.attention import (
-    attention_decode,
+    attention_decode_attend,
+    attention_decode_qkv,
     attention_forward,
     init_attention,
+    write_decode_kv,
 )
 from repro.models.cache import attn_cache_len, init_layer_cache
 from repro.models.config import (
@@ -229,6 +231,28 @@ def _kv_to_cache(cfg, spec, k, v, positions, max_len):
     return {"k": ck, "v": cv, "pos": cp}
 
 
+def _unit_read(tree, unit):
+    """One unit's view of a stacked cache pytree (the tree itself when
+    ``unit`` is None: a plain per-layer cache)."""
+    if unit is None:
+        return tree
+    return jax.tree.map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, unit, 0, keepdims=False), tree
+    )
+
+
+def _unit_write(tree, new, unit):
+    """Writes one unit's new state into a stacked cache pytree at ``unit``
+    (``new`` itself when ``unit`` is None)."""
+    if unit is None:
+        return new
+    return jax.tree.map(
+        lambda a, n: jax.lax.dynamic_update_index_in_dim(
+            a, n.astype(a.dtype), unit, 0),
+        tree, new,
+    )
+
+
 def apply_layer_decode(
     lp: dict,
     spec: LayerSpec,
@@ -238,19 +262,32 @@ def apply_layer_decode(
     cfg: ModelConfig,
     ctx: Optional[MoEShardingCtx],
     mrope_position: Optional[jnp.ndarray],
+    unit=None,
 ):
+    """One layer, one token per slot: returns (x, cache).
+
+    ``cache`` is the layer's plain ``(B, ...)`` cache, or with ``unit`` (a
+    traced int32) the layer's stacked ``(num_units, B, ...)`` cache carried
+    through the decode scan: the layer writes its new K/V rows or state at
+    ``[unit]`` and hands the whole stack on, so no copy of it is emitted."""
     h = apply_norm(lp["norm1"], x, cfg)
     if spec.mixer.startswith("attn"):
-        mixed, ck, cv, cp = attention_decode(
-            lp["mixer"], h, position, cache["k"], cache["v"], cache["pos"],
-            cfg, spec.mixer, mrope_position=mrope_position,
+        q, k, v = attention_decode_qkv(lp["mixer"], h, position, cfg,
+                                       mrope_position)
+        new_cache = write_decode_kv(cache, k, v, position, unit)
+        own = _unit_read(new_cache, unit)
+        mixed = attention_decode_attend(
+            lp["mixer"], q, position, own["k"], own["v"], own["pos"],
+            cfg, spec.mixer,
         )
-        new_cache = {"k": ck, "v": cv, "pos": cp}
     elif spec.mixer == "mamba":
-        mixed, new_cache = mamba_mod.mamba_step(lp["mixer"], h, cfg, cache)
+        mixed, state = mamba_mod.mamba_step(lp["mixer"], h, cfg,
+                                            _unit_read(cache, unit))
+        new_cache = _unit_write(cache, state, unit)
     elif spec.mixer == "rwkv6":
-        mixed, tm = rwkv_mod.rwkv_time_mix_step(lp["mixer"], h, cfg, cache["tm"])
-        new_cache = dict(cache, tm=tm)
+        mixed, tm = rwkv_mod.rwkv_time_mix_step(
+            lp["mixer"], h, cfg, _unit_read(cache["tm"], unit))
+        new_cache = dict(cache, tm=_unit_write(cache["tm"], tm, unit))
     else:
         raise ValueError(spec.mixer)
     x = x + mixed
@@ -263,10 +300,10 @@ def apply_layer_decode(
             x = x + y
         elif spec.mlp == MLP_RWKV:
             y, cm = rwkv_mod.rwkv_channel_mix_forward(
-                lp["mlp"], h2, cfg, state=new_cache.get("cm")
+                lp["mlp"], h2, cfg, state=_unit_read(new_cache["cm"], unit)
             )
             x = x + y
-            new_cache["cm"] = cm
+            new_cache["cm"] = _unit_write(new_cache["cm"], cm, unit)
     return x, new_cache
 
 
@@ -386,21 +423,25 @@ def decode_step(
         x = embeds
 
     if cfg.num_units:
-        def body(xc, scanned):
-            unit_params, unit_cache = scanned
-            new_caches = []
+        # The stacked unit cache rides in the carry: each layer scatters its
+        # new rows into it at the unit's index and attends over its own
+        # slice, so a donated cache is updated in place.  It must not be a
+        # scan output: each output is a fresh buffer, a second whole cache
+        # written every tick.
+        def body(carry, unit_params):
+            xc, unit_cache, u = carry
+            layers = list(unit_cache)
             for i, spec in enumerate(cfg.unit):
-                xc, nc = apply_layer_decode(
-                    unit_params[i], spec, xc, position, unit_cache[i], cfg, ctx,
-                    mrope_position,
+                xc, layers[i] = apply_layer_decode(
+                    unit_params[i], spec, xc, position, layers[i], cfg, ctx,
+                    mrope_position, unit=u,
                 )
                 if hasattr(ctx, "act"):
                     xc = ctx.act(xc)
-                new_caches.append(nc)
-            return xc, tuple(new_caches)
+            return (xc, tuple(layers), u + 1), None
 
-        x, new_unit_caches = jax.lax.scan(
-            body, x, (params["units"], cache["units"])
+        (x, new_unit_caches, _), _ = jax.lax.scan(
+            body, (x, cache["units"], jnp.int32(0)), params["units"]
         )
     else:
         new_unit_caches = ()

@@ -182,7 +182,10 @@ class ServeEngine:
             next_tok, new_cache = decode_step(params, tokens, positions, cache, mp)
             return next_tok, positions + 1, new_cache
 
-        # positions/cache donated: the step rewrites the KV cache in place.
+        # positions/cache donated: the cache is updated in place, because
+        # ``models.transformer.decode_step`` carries the stacked unit cache
+        # through its layer scan and scatters each layer's new rows into the
+        # carry (a cache emitted as a scan output would be a second copy).
         # The token vector is NOT donated — the previous tick's tokens are
         # still held by the deferred-fetch queue.
         self._tick = jax.jit(tick, donate_argnums=(2, 3))
