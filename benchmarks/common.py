@@ -1,13 +1,8 @@
-"""Shared helpers for the benchmark harness."""
+"""Shared helpers for the paper's experiments."""
 from __future__ import annotations
 
 import time
-from typing import Callable, Dict, Optional
-
-# rows emitted by the current benchmark module: name -> {us, bytes, derived}.
-# run.py snapshots this per module to build machine-readable outputs
-# (BENCH_kernels.json) that track the perf trajectory across PRs.
-RESULTS: Dict[str, Dict] = {}
+from typing import Callable
 
 
 def _block(out):
@@ -31,8 +26,5 @@ def time_us(fn: Callable, *args, warmup: int = 2, iters: int = 10) -> float:
     return (time.perf_counter() - t0) / iters * 1e6
 
 
-def emit(name: str, us_per_call: float, derived: str = "",
-         nbytes: Optional[int] = None) -> None:
+def emit(name: str, us_per_call: float, derived: str = "") -> None:
     print(f"{name},{us_per_call:.1f},{derived}")
-    RESULTS[name] = {"us": round(us_per_call, 1), "bytes": nbytes,
-                     "derived": derived}

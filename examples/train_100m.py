@@ -1,6 +1,6 @@
 """End-to-end driver: trains a ~100M-parameter decoder for a few hundred
-steps on synthetic Markov-chain data with the production train_step — the
-same step the multi-pod dry-run lowers, here on the host mesh.
+steps on synthetic Markov-chain data with the production train_step
+(``launch/steps.py``), here on the host mesh.
 
   PYTHONPATH=src python examples/train_100m.py [--steps 300] [--mode bflc]
 """

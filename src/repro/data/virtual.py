@@ -3,11 +3,10 @@
 ``VirtualFederatedDataset`` presents ``num_clients`` virtual clients over a
 small base ``FederatedDataset`` by mapping virtual client ``i`` to base shard
 ``i % base.num_clients``.  The shard arrays are *aliased*, never copied, so a
-100k-client community costs the same host memory as its 32-shard base — the
-point of the hierarchical-round benchmarks is to measure the *round engine's*
-memory/scaling behaviour (update-stack bytes, committee work) at large P, and
-that behaviour depends only on how many clients train, not on how distinct
-their bytes are.
+100k-client community costs the same host memory as its 32-shard base — a
+virtual community exercises the *round engine's* memory/scaling behaviour
+(update-stack bytes, committee work) at large P, and that behaviour depends
+only on how many clients train, not on how distinct their bytes are.
 
 The view quacks like ``FederatedDataset`` everywhere the round engines look:
 ``client_images[i]`` / ``client_labels[i]`` indexing, ``num_clients``,

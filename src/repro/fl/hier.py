@@ -72,8 +72,8 @@ class HierState:
 
     The runtime builds one per round (``cfg.tiers > 1``); stages fill it
     in.  ``peak_stack_bytes`` is the measured high-water mark of update
-    stacks held at once — the quantity ``hier_bench`` reports against the
-    O(P·D) flat equivalent (``flat_stack_bytes``)."""
+    stacks held at once, to set against the O(P·D) flat equivalent
+    (``flat_stack_bytes``); ``rt.hier_logs`` carries both per round."""
 
     tiers: int
     inner_validator: Any

@@ -2,9 +2,9 @@
 
 The sharded round engine needs multiple devices; on CPU-only hosts XLA
 fakes them via ``--xla_force_host_platform_device_count``.  The flag is
-only read when jax initializes its backend, so callers (tests/conftest.py,
-benchmarks/run.py) must invoke this before anything touches jax — which is
-also why this module must never import jax itself.
+only read when jax initializes its backend, so callers (tests/conftest.py)
+must invoke this before anything touches jax — which is also why this module
+must never import jax itself.
 """
 from __future__ import annotations
 

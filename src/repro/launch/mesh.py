@@ -1,5 +1,5 @@
-"""Production mesh construction (multi-pod dry-run spec) + the round engine's
-data mesh.
+"""Mesh construction: the small host mesh the serving and LM steps take, and
+the round engine's data mesh.
 
 Functions, not module-level constants: importing this module never touches
 jax device state.
@@ -14,13 +14,6 @@ import numpy as np
 
 def _auto_axes(n: int) -> tuple:
     return (jax.sharding.AxisType.Auto,) * n
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """16x16 = 256 chips per pod; 2 pods = 512 chips when multi_pod."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, axis_types=_auto_axes(len(axes)))
 
 
 def make_host_mesh(data: int = 1, model: int = 1):

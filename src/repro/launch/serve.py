@@ -5,8 +5,8 @@ baseline over the same compiled prefill/decode steps.
       --slots 4 --requests 16 --rate 20 --max-len 96
 
 ``--static`` switches the admission policy to the legacy whole-batch
-barrier (all requests of a batch start and finish together) — the baseline
-``BENCH_serve.json`` compares against.  The heavy lifting lives in
+barrier (all requests of a batch start and finish together).  The heavy
+lifting lives in
 ``repro.serve``; this module only parses flags, builds the trace, and
 prints the measured metrics.
 """

@@ -17,8 +17,8 @@ Two training flavours:
   are downweighted — the same robustness mechanism the FL runtime implements
   exactly at node granularity.
 
-All steps take explicit in/out shardings from shardings.py and are meant to
-be ``jax.jit(...).lower(...).compile()``-ed by launch/dryrun.py.
+All steps take explicit in/out shardings from shardings.py; the serving
+engine and ``launch/train.py`` jit them.
 """
 from __future__ import annotations
 

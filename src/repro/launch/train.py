@@ -7,7 +7,7 @@ Two entry modes:
   stand-alone comparisons.  This is the faithful-reproduction driver.
 * ``--driver lm``   — the production pipeline scaled to this container: a
   ~100M-parameter decoder trained for a few hundred steps on synthetic
-  Markov-chain data with the same sharded train_step the dry-run compiles
+  Markov-chain data with the sharded train_step of ``launch/steps.py``
   (host mesh), in either ``standard`` or ``bflc`` (committee-weighted) mode.
 
 Examples:

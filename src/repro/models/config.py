@@ -6,7 +6,7 @@ of layers (e.g. Jamba's ``7 x mamba + 1 x attn`` period, Gemma-3's
 optional non-repeating ``tail``.  Homogeneous models (most) have a unit of a
 single layer.  The repeating structure lets the forward pass ``lax.scan`` over
 stacked unit parameters, which keeps HLO size and compile time independent of
-depth — essential for the 33-combination multi-pod dry-run.
+depth.
 """
 from __future__ import annotations
 

@@ -4,7 +4,7 @@ The driver models the BFLC deployment story: a large user population hits a
 serving node with Poisson arrivals and mixed prompt/generation lengths.
 Metrics follow the standard serving vocabulary — tokens/s, TTFT (arrival to
 first generated token) and end-to-end request latency, p50/p99 over the
-request population — and land in ``BENCH_serve.json`` rows.
+request population — and land in ``ServeReport.metrics()``.
 """
 from __future__ import annotations
 
@@ -57,7 +57,7 @@ def aggregate(
     occupancy: float,
     swaps: int = 0,
 ) -> Dict[str, float]:
-    """One BENCH_serve.json row from a finished run."""
+    """The metrics of a finished run, as ``ServeReport.metrics()`` returns them."""
     gen = sum(len(r.tokens) for r in results)
     ttft = [r.first_token - r.arrival for r in results if r.first_token >= 0]
     lat = [r.finished - r.arrival for r in results if r.finished >= 0]

@@ -13,8 +13,7 @@ from repro.hostdevices import force_host_devices
 # The suite runs on CPU with 8 forced host devices so the sharded round
 # engine (repro.fl.sharded) is exercised for real — shard_map over 1/2/8
 # devices — without a TPU.  Flags land before jax initializes its backend;
-# an externally-provided force_host flag wins.  The dry-run still sets its
-# own XLA_FLAGS in a separate process.
+# an externally-provided force_host flag wins.
 os.environ.setdefault("JAX_PLATFORMS", "cpu")
 force_host_devices()
 

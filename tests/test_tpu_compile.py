@@ -7,7 +7,8 @@ kernels in compiled mode at the width of the paper's model (femnist-cnn at
 width 32: D = 430080 after padding, K = 8 update rows) for a v5e chip that
 is described, not attached, and check that each program holds a Mosaic
 kernel (``tpu_custom_call``).  Nothing runs, so results are not checked
-here; the kernel tests in interpret mode and ``chip_smoke.py`` on the chip
+here; the kernel tests in interpret mode and the benchmark (``bench/``, whose
+round cell checks the int8 round against a float32 reference) on the chip
 do that.
 
 The topology is described inside a fixture only: describing it loads the
