@@ -9,6 +9,7 @@ and pytree-level helpers adapt model updates.
   aggregate(stack, method=..., weights=..., trim=...)        f32 path
   aggregate_quantized(q, scales, method=..., ...)            fused int8 path
   quantize_stack(stack)                                      round codec
+  decode_attention(q, k, v, pos, q_pos, unit, block=...)     serving tick
 
 The sharded multi-device engine builds its programs once through the
 factories at the bottom (``make_quantize_stack_sharded`` /
@@ -25,6 +26,7 @@ import jax.numpy as jnp
 
 from repro.kernels import ref
 from repro.kernels.cwmed import cwmed_kernel, trimmed_mean_kernel
+from repro.kernels.decode_attention import ragged_decode_attention_kernel
 from repro.kernels.fedavg_agg import fedavg_agg_kernel
 from repro.kernels.tiling import BLOCK_D
 from repro.kernels.fused_agg import METHODS, fused_agg_kernel
@@ -115,6 +117,16 @@ def cwmed(stack: jnp.ndarray) -> jnp.ndarray:
 def trimmed_mean(stack: jnp.ndarray, trim: int = 1) -> jnp.ndarray:
     """(K, D) -> (D,) coordinate-wise trimmed mean via the Pallas kernel."""
     return aggregate(stack, "trimmed_mean", trim=trim)
+
+
+def decode_attention(q, k, v, pos, q_pos, unit, *, block: int) -> jnp.ndarray:
+    """One query token per slot over the decode scan's stacked slot cache,
+    reading only the blocks of ``block`` rows that each live slot holds
+    (``kernels/decode_attention.py``): (B, H, Dh) scaled float32 queries,
+    (U, B, Sc, Kv, Dh) K and V, (U, B, Sc) positions, (B,) query positions
+    (-1 for a free slot) and the layer's unit index -> (B, H, Dh)."""
+    return ragged_decode_attention_kernel(q, k, v, pos, q_pos, unit,
+                                          block=block, interpret=_interpret())
 
 
 # ----------------------------------------------------------------------

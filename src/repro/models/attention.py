@@ -1,10 +1,13 @@
 """Grouped-query attention with RoPE / M-RoPE, causal, bidirectional and
 sliding-window masking, plus a KV cache for decode.
 
-Two execution paths:
+Three execution paths:
 
 * ``_dense_attention``  — materializes (S_q, S_kv) scores; used for short
-  sequences (<= DENSE_MAX) and single-token decode.
+  sequences (<= DENSE_MAX) and for single-token decode over a cache the
+  ragged kernel does not cover (``ragged_block``).
+* the ragged decode kernel (``kernels/decode_attention.py``) — single-token
+  decode that reads only the cache blocks each live slot holds.
 * ``_chunked_attention`` — flash-style online-softmax over KV blocks via
   ``lax.scan`` (outer scan over Q blocks, inner over KV blocks).  Never
   materializes more than (q_block, kv_block) scores, so 32k prefill and the
@@ -20,11 +23,14 @@ from typing import NamedTuple, Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.ops import decode_attention
 from repro.models.config import (
     ATTN,
     ATTN_GLOBAL,
     ATTN_LOCAL,
     ATTN_SWA,
+    MAMBA,
+    RWKV,
     ModelConfig,
 )
 from repro.models.layers import apply_mrope, apply_rope, dense_init
@@ -314,38 +320,87 @@ def write_decode_kv(
     stacked ``(num_units, B, Sc, ...)`` arrays of the decode scan's carry,
     written at ``[unit]``: one row per slot, whatever the stack's size.
     Sliding-window layers use a ring buffer: slot = position % Sc.  For
-    full-attention layers Sc == max_len, so the slot is the position.
+    full-attention layers Sc == max_len, so the slot is the position.  A
+    free slot (position -1) writes nothing: its row index lies past the
+    cache and the scatter drops it.
     """
-    slot = position % cache["pos"].shape[-1]
+    Sc = cache["pos"].shape[-1]
+    slot = jnp.where(position >= 0, position % Sc, Sc)
     idx = (jnp.arange(position.shape[0]), slot)
     if unit is not None:
         idx = (unit,) + idx
     return {
-        "k": cache["k"].at[idx].set(k[:, 0]),
-        "v": cache["v"].at[idx].set(v[:, 0]),
-        "pos": cache["pos"].at[idx].set(position),
+        "k": cache["k"].at[idx].set(k[:, 0], mode="drop"),
+        "v": cache["v"].at[idx].set(v[:, 0], mode="drop"),
+        "pos": cache["pos"].at[idx].set(position, mode="drop"),
     }
+
+
+def ragged_block(cfg: ModelConfig, mixer: str, cache_len: int,
+                 mesh=None) -> int:
+    """Rows per block with which the decode tick reads this layer's cache
+    through the ragged kernel (``kernels/decode_attention.py``), or 0 where
+    the layer attends densely over every row: a ring-buffer windowed layer,
+    soft-capped or bidirectional attention, a cache sharded over a mesh of
+    several devices, a model whose decode scan also steps recurrent
+    (mamba, rwkv6) layers, or a cache of more than 256 rows that neither
+    256 nor 128 divides.
+
+    Beside recurrent layers the kernel slowed the tick: in a Jamba tick on
+    a v5e it took 0.03-0.16 ms, but the mamba layers' state and parameter
+    copies around it waited 1.96 ms longer, against the 0.57 ms of dense
+    attention it replaced."""
+    if (is_windowed(mixer) or cfg.attn_logit_softcap > 0 or not cfg.causal
+            or (mesh is not None and mesh.size > 1)
+            or cfg.has_mixer(MAMBA) or cfg.has_mixer(RWKV)):
+        return 0
+    for block in (256, 128):
+        if cache_len % block == 0:
+            return block
+    # a cache of another length is one block where it fits in fast memory
+    return cache_len if cache_len <= 256 else 0
 
 
 def attention_decode_attend(
     params: dict,
     q: jnp.ndarray,            # (B,1,H,Dh) rotated
-    position: jnp.ndarray,     # (B,)
-    cache_k: jnp.ndarray,      # (B,Sc,Kv,Dh) rotated keys, the new row written
-    cache_v: jnp.ndarray,      # (B,Sc,Kv,Dh)
-    cache_pos: jnp.ndarray,    # (B,Sc) absolute position per slot (-1 invalid)
+    position: jnp.ndarray,     # (B,) -1 for a free slot
+    cache: dict,               # the new row written; see write_decode_kv
     cfg: ModelConfig,
     mixer: str,
+    unit=None,
+    ctx=None,
 ) -> jnp.ndarray:
     """Single-token attention over a (possibly ring-buffer) KV cache that
-    already holds the new token's row: returns the mixer output (B,1,D)."""
-    window = cfg.sliding_window if is_windowed(mixer) else 0
-    q_pos = position[:, None]                       # (B,1)
-    # q_len == 1: dense attention is O(B*H*Skv) — no S^2 blowup — and the
-    # softmax reduction over a seq-sharded cache lowers to small psums
-    # (a blocked scan would dynamic-slice the sharded seq axis and force XLA
-    # to replicate the whole cache per step).
-    mask = _pair_mask(q_pos, cache_pos, causal=cfg.causal, window=window)
-    out = _dense_attention(q, cache_k, cache_v, mask, cfg.attn_logit_softcap)
-    B = out.shape[0]
+    already holds the new token's row: returns the mixer output (B,1,D).
+
+    ``cache`` is one layer's ``(B, Sc, ...)`` cache or, with ``unit``, the
+    decode scan's stacked carry.  Where ``ragged_block`` allows, the kernel
+    reads each live slot's rows up to its position straight from the stack;
+    else dense attention runs over the unit's whole cache."""
+    B, _, _, Dh = q.shape
+    block = ragged_block(cfg, mixer, cache["pos"].shape[-1],
+                         getattr(ctx, "mesh", None))
+    if block:
+        stack = cache if unit is not None else jax.tree.map(
+            lambda a: a[None], cache)
+        qf = q[:, 0].astype(jnp.float32) * (Dh ** -0.5)
+        out = decode_attention(
+            qf, stack["k"], stack["v"], stack["pos"], position,
+            0 if unit is None else unit, block=block,
+        ).astype(q.dtype)
+    else:
+        if unit is not None:
+            cache = jax.tree.map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, unit, 0, False),
+                cache)
+        window = cfg.sliding_window if is_windowed(mixer) else 0
+        # q_len == 1: dense attention is O(B*H*Skv) — no S^2 blowup — and
+        # the softmax reduction over a seq-sharded cache lowers to small
+        # psums (a blocked scan would dynamic-slice the sharded seq axis and
+        # force XLA to replicate the whole cache per step).
+        mask = _pair_mask(position[:, None], cache["pos"], causal=cfg.causal,
+                          window=window)
+        out = _dense_attention(q, cache["k"], cache["v"], mask,
+                               cfg.attn_logit_softcap)
     return out.reshape(B, 1, -1) @ params["wo"]

@@ -275,11 +275,8 @@ def apply_layer_decode(
         q, k, v = attention_decode_qkv(lp["mixer"], h, position, cfg,
                                        mrope_position)
         new_cache = write_decode_kv(cache, k, v, position, unit)
-        own = _unit_read(new_cache, unit)
-        mixed = attention_decode_attend(
-            lp["mixer"], q, position, own["k"], own["v"], own["pos"],
-            cfg, spec.mixer,
-        )
+        mixed = attention_decode_attend(lp["mixer"], q, position, new_cache,
+                                        cfg, spec.mixer, unit, ctx)
     elif spec.mixer == "mamba":
         mixed, state = mamba_mod.mamba_step(lp["mixer"], h, cfg,
                                             _unit_read(cache, unit))

@@ -11,6 +11,13 @@ finish detection is count-based, so the hot loop never blocks on token
 values: each tick's token vector is fetched one tick late, while the next
 tick is already in flight on device.
 
+A free slot holds position -1 on the device: the tick advances only live
+slots, the attention kernel reads no block of a free slot and only the
+blocks a live slot holds up to its position, and a freed slot is set back
+to -1 by one small jitted update.  ``ServeReport.kv_read_share`` is the
+mean share of the attention layers' allocated KV rows that a tick reads,
+counted after the run from each request's prompt length and tokens.
+
 Each phase of the loop runs inside an ``engine.<phase>`` span
 (``repro.tracing``: admit, prefill, insert, tick, fetch, swap, idle); the
 run's host seconds per span come back as ``ServeReport.host_s``.
@@ -23,7 +30,7 @@ requests keep their caches and keep decoding; nothing is dropped.
 from __future__ import annotations
 
 import time
-from collections import deque
+from collections import Counter, deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, Deque, Dict, List, Optional, Sequence, Tuple
 
@@ -35,7 +42,8 @@ from repro.launch.mesh import make_host_mesh
 from repro.launch.shardings import ShardingPolicy
 from repro.launch.steps import make_decode_step, make_prefill_step
 from repro.models import init_cache
-from repro.models.cache import insert_slot_cache
+from repro.models.attention import ragged_block
+from repro.models.cache import attn_cache_len, insert_slot_cache
 from repro.models.config import ModelConfig
 from repro.models.transformer import Batch
 from repro.serve.scheduler import FifoScheduler
@@ -114,12 +122,15 @@ class ServeReport:
     policy: str
     # host seconds inside each ``engine.<phase>`` span over the run
     host_s: Dict[str, float] = field(default_factory=dict)
+    # mean over ticks of the attention layers' KV rows read / rows allocated
+    kv_read_share: float = 0.0
 
     def metrics(self) -> Dict[str, float]:
         out = aggregate(
             self.results, wall_s=self.wall_s, ticks=self.ticks,
             occupancy=self.occupancy, swaps=len(self.swaps),
         )
+        out["kv_read_share"] = round(self.kv_read_share, 4)
         per_tick = max(1, self.ticks)
         for name, secs in sorted(self.host_s.items()):
             out[f"{name}_ms_per_tick"] = round(secs * 1e3 / per_tick, 4)
@@ -127,6 +138,35 @@ class ServeReport:
 
     def by_rid(self) -> Dict[int, RequestResult]:
         return {r.rid: r for r in self.results}
+
+
+def kv_layers(cfg: ModelConfig, max_len: int, mesh=None) -> Counter:
+    """The attention layers, counted by ``(block, rows)``: the rows per
+    block in which the decode tick reads a layer's cache (0: every row,
+    ``models.attention.ragged_block``) and the rows each slot holds."""
+    layers: Counter = Counter()
+    for spec in cfg.all_layers():
+        if spec.mixer.startswith("attn"):
+            sc = attn_cache_len(cfg, spec.mixer, max_len)
+            layers[ragged_block(cfg, spec.mixer, sc, mesh), sc] += 1
+    return layers
+
+
+def kv_rows_read(layers: Counter, num_slots: int, live_pos: np.ndarray,
+                 ticks: int = 1) -> Tuple[int, int]:
+    """(KV rows read, KV rows allocated) over ``layers`` (``kv_layers``)
+    in ``ticks`` ticks whose live slots, all ticks' together, sit at
+    ``live_pos``: a ragged layer reads each live slot's blocks up to its
+    position, a dense layer every row of every slot."""
+    read = alloc = 0
+    for (block, sc), n in layers.items():
+        alloc += ticks * n * num_slots * sc
+        if block:
+            need = np.minimum(np.asarray(live_pos) // block + 1, sc // block)
+            read += n * int(need.sum()) * block
+        else:
+            read += ticks * n * num_slots * sc
+    return read, alloc
 
 
 class ServeEngine:
@@ -180,7 +220,9 @@ class ServeEngine:
                 if mrope else None
             )
             next_tok, new_cache = decode_step(params, tokens, positions, cache, mp)
-            return next_tok, positions + 1, new_cache
+            # a free slot (-1) stays free
+            positions = jnp.where(positions >= 0, positions + 1, -1)
+            return next_tok, positions, new_cache
 
         # positions/cache donated: the cache is updated in place, because
         # ``models.transformer.decode_step`` carries the stacked unit cache
@@ -197,6 +239,9 @@ class ServeEngine:
             return tokens, positions, cache
 
         self._insert = jax.jit(insert, donate_argnums=(0, 2))
+        self._free = jax.jit(lambda positions, b: positions.at[b].set(-1),
+                             donate_argnums=0)
+        self._kv_layers = kv_layers(cfg, max_len, mesh)
         # host seconds per ``engine.<phase>`` span of the current run
         self._host_s: Dict[str, float] = {}
 
@@ -219,7 +264,7 @@ class ServeEngine:
 
     def _fresh_state(self):
         tokens = jnp.zeros((self.num_slots, 1), jnp.int32)
-        positions = jnp.zeros((self.num_slots,), jnp.int32)
+        positions = jnp.full((self.num_slots,), -1, jnp.int32)
         cache = init_cache(self.cfg, self.num_slots, self.max_len,
                            jnp.dtype(self.cfg.dtype))
         return tokens, positions, cache
@@ -239,7 +284,8 @@ class ServeEngine:
         tokens, positions, cache = self._tick(
             self.params, tokens, positions, cache
         )
-        jax.block_until_ready(tokens)
+        positions = self._free(positions, b)
+        jax.block_until_ready(positions)
 
     # ------------------------------------------------------------------
     def _poll_swap(self, tick_idx: int, clock, swaps: List[dict]) -> None:
@@ -366,6 +412,8 @@ class ServeEngine:
                                         version=self.version))
                 for b in done_slots:
                     table.release(b)
+                    positions = self._free(positions,
+                                           jnp.asarray(b, jnp.int32))
                 active_ticks += len(deliveries)
                 tick_idx += 1
                 clock.tick()
@@ -386,6 +434,12 @@ class ServeEngine:
         occupancy = (active_ticks / (tick_idx * self.num_slots)
                      if tick_idx else 0.0)
         ordered = [results[r.rid] for r in sorted(requests, key=lambda q: q.rid)]
+        # a request that got n tokens decoded in n - 1 ticks, from its prompt
+        live_pos = [r.prompt_len + j for r in ordered
+                    for j in range(len(r.tokens) - 1)]
+        rows, alloc = kv_rows_read(self._kv_layers, self.num_slots, live_pos,
+                                   tick_idx)
         return ServeReport(results=ordered, wall_s=wall, ticks=tick_idx,
                            occupancy=occupancy, swaps=swaps, policy=policy,
-                           host_s=dict(host_s))
+                           host_s=dict(host_s),
+                           kv_read_share=rows / alloc if alloc else 0.0)

@@ -189,11 +189,17 @@ def test_engine_rejects_oversized_request(cfg, params):
 # ----------------------------------------------------------------------------
 
 
-@pytest.mark.parametrize("policy", ["continuous", "static"])
-def test_engine_matches_single_request_oracle(cfg, params, host_steps, policy):
+@pytest.mark.parametrize("policy,num_slots", [
+    pytest.param("continuous", 3, id="continuous"),
+    pytest.param("static", 3, id="static"),
+    # more slots than requests in flight: each decodes beside free slots
+    pytest.param("continuous", 8, id="continuous-free-slots"),
+])
+def test_engine_matches_single_request_oracle(cfg, params, host_steps, policy,
+                                              num_slots):
     prefill, decode = host_steps
     trace = mixed_trace(cfg)
-    eng = ServeEngine(cfg, params, num_slots=3, max_len=MAX_LEN)
+    eng = ServeEngine(cfg, params, num_slots=num_slots, max_len=MAX_LEN)
     rep = eng.run(trace, policy=policy, clock=VirtualClock())
     assert rep.policy == policy
     for res, req in zip(rep.results, trace):
@@ -204,6 +210,74 @@ def test_engine_matches_single_request_oracle(cfg, params, host_steps, policy):
     assert m["requests"] == len(trace)
     assert m["generated_tokens"] == sum(r.max_new for r in trace)
     assert 0.0 < rep.occupancy <= 1.0
+
+
+def test_freed_slot_stays_free_until_insert(cfg, params):
+    """A free slot holds position -1: ticks neither advance it nor write
+    its cache rows; a freed slot returns to -1, and an insert revives it
+    at its prompt length."""
+    eng = ServeEngine(cfg, params, num_slots=3, max_len=MAX_LEN)
+    tokens, positions, cache = eng._fresh_state()
+    np.testing.assert_array_equal(np.asarray(positions), [-1, -1, -1])
+    prompt = np.arange(1, 9, dtype=np.int32)
+
+    def insert(tokens, positions, cache, b):
+        tok, slot_cache = eng._prefill(params, eng._make_prompt_batch(prompt))
+        return eng._insert(cache, tokens, positions, slot_cache, tok,
+                           jnp.asarray(len(prompt), jnp.int32),
+                           jnp.asarray(b, jnp.int32))
+
+    def ticks(n, tokens, positions, cache):
+        for _ in range(n):
+            tokens, positions, cache = eng._tick(params, tokens, positions,
+                                                 cache)
+        return tokens, positions, cache
+
+    tokens, positions, cache = insert(tokens, positions, cache, 1)
+    tokens, positions, cache = ticks(3, tokens, positions, cache)
+    np.testing.assert_array_equal(np.asarray(positions), [-1, 11, -1])
+    for layer in cache["units"]:
+        # free slots: no row written; slot 1: its prompt and three ticks
+        assert (np.asarray(layer["pos"][:, [0, 2]]) == -1).all()
+        assert (np.asarray(layer["pos"][:, 1, :11]) == np.arange(11)).all()
+
+    positions = eng._free(positions, jnp.asarray(1, jnp.int32))
+    tokens, positions, cache = ticks(2, tokens, positions, cache)
+    np.testing.assert_array_equal(np.asarray(positions), [-1, -1, -1])
+    for layer in cache["units"]:
+        # the freed slot's rows stay as its request left them
+        assert (np.asarray(layer["pos"][:, 1, :11]) == np.arange(11)).all()
+        assert (np.asarray(layer["pos"][:, 1, 11:]) == -1).all()
+
+    tokens, positions, cache = insert(tokens, positions, cache, 1)
+    np.testing.assert_array_equal(np.asarray(positions), [-1, 8, -1])
+    tokens, positions, cache = ticks(1, tokens, positions, cache)
+    np.testing.assert_array_equal(np.asarray(positions), [-1, 9, -1])
+
+
+def test_kv_read_share_counts_block_rounded_rows(cfg, params):
+    """Two requests of known lengths on a 384-row cache, read in blocks of
+    128 rows: each tick reads, per live slot, its blocks up to its
+    position, over the rows the slots hold."""
+    max_len, block, slots = 384, 128, 2
+    rng = np.random.default_rng(5)
+    trace = [
+        Request(rid=0, prompt=rng.integers(0, 64, (120,)).astype(np.int32),
+                max_new=20),
+        Request(rid=1, prompt=rng.integers(0, 64, (10,)).astype(np.int32),
+                max_new=5),
+    ]
+    eng = ServeEngine(cfg, params, num_slots=slots, max_len=max_len)
+    rep = eng.run(trace, clock=VirtualClock())
+    assert rep.ticks == 19
+
+    def rows(p):
+        return (p // block + 1) * block
+
+    shares = [(rows(120 + t) + (rows(10 + t) if t < 4 else 0))
+              / (slots * max_len) for t in range(rep.ticks)]
+    assert rep.kv_read_share == pytest.approx(np.mean(shares))
+    assert rep.metrics()["kv_read_share"] == round(rep.kv_read_share, 4)
 
 
 def test_continuous_frees_slots_static_stalls(cfg, params):

@@ -15,6 +15,7 @@ The topology is described inside a fixture only: describing it loads the
 TPU library, which one process at a time may hold.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -27,6 +28,8 @@ from repro.kernels.tiling import BLOCK_D
 K = 8
 D = 430080                       # femnist-cnn width 32, padded to BLOCK_D
 NBLK = D // BLOCK_D
+# an HLO instruction: its result shape and its opcode
+_OP = re.compile(r"\s*(?:ROOT )?%\S+ = (\S+) ([\w-]+)\(")
 
 
 @pytest.fixture(scope="module")
@@ -151,3 +154,32 @@ def test_int8_scorer_compiles(compiled_kernels, one_chip):
         _sds((8, 64), jnp.int32, one_chip),
     ).compile().as_text()
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("U,B,Sc,Kv,H", [
+    (16, 16, 1024, 16, 16),      # olmo-1b: 16 slots x 1024, one query per head
+    (2, 16, 4352, 1, 20),        # jamba2-3b: one KV head for 20 queries
+], ids=["olmo-1b", "jamba2-3b"])
+def test_decode_attention_compiles(compiled_kernels, one_chip, U, B, Sc, Kv,
+                                   H):
+    """The serving tick's ragged attention over the decode scan's whole
+    stacked slot cache: the kernel reads the stacks where they lie, so the
+    program holds no copy of them."""
+    Dh = 128
+
+    def attend(q, k, v, pos, q_pos, unit):
+        return ops.decode_attention(q, k, v, pos, q_pos, unit, block=256)
+
+    text = _compile(attend, _sds((B, H, Dh), jnp.float32, one_chip),
+                    _sds((U, B, Sc, Kv, Dh), jnp.float32, one_chip),
+                    _sds((U, B, Sc, Kv, Dh), jnp.float32, one_chip),
+                    _sds((U, B, Sc), jnp.int32, one_chip),
+                    _sds((B,), jnp.int32, one_chip),
+                    _sds((), jnp.int32, one_chip))
+    # no op but the operands themselves makes a stack-sized or unit-sized
+    # array beside the kernel
+    whole = re.compile(rf"\[(\d+,)?{B},{Sc},{Kv},{Dh}\]")
+    ops_made = [m.groups() for m in map(_OP.match, text.splitlines()) if m]
+    assert ops_made
+    assert not [(shape, op) for shape, op in ops_made if whole.search(shape)
+                and op not in ("parameter", "custom-call")]
